@@ -19,7 +19,9 @@ Two numbers per arm:
 The gate is asserted only when the machine actually has >= ``GATE_DEVICES``
 CPU cores (virtual devices on one core time-slice it — no speedup exists
 to measure); below that the row records the measurement with
-``"enforced": false``.
+``"enforced": false``.  The benchmark refuses to run on an accelerator
+backend: the chip belongs to one process, and ``chip_smoke.py --chips 4``
+covers the multi-chip path there.
 """
 from __future__ import annotations
 
@@ -111,6 +113,16 @@ def _run_arm(n_dev: int) -> dict:
 
 
 def bench_shard():
+    # the arms are child processes on virtual CPU devices; on an
+    # accelerator this process already holds the chip and they could not
+    # open it
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"bench_shard runs its arms on virtual CPU devices, not on "
+            f"{backend!r}; for the multi-chip path run "
+            f"`python chip_smoke.py --chips 4`")
     arms = {n: _run_arm(n) for n in (1, GATE_DEVICES)}
     eval_speedup = (arms[1]["eval_seconds"]
                     / max(arms[GATE_DEVICES]["eval_seconds"], 1e-12))

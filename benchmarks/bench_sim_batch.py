@@ -186,66 +186,57 @@ def bench_sim_batch():
                  f"chain+balancer workload)"))
 
     # jax.lax.scan backend (compile once, report steady-state)
-    try:
-        idx = survivors[:512]
-        bplat = BatchSimPlatform.from_design_points(m, res, idx,
-                                                    req_mb=REQ_MB)
-        ctl = BatchControllerHarness(bplat.islands, bplat.rates,
-                                     BatchPIDRatePolicy(target=0.7),
-                                     tile_names=bplat.names,
-                                     queue_guard_ticks=3.0)
-        eng = BatchSimEngine(bplat, config=SimConfig(control_interval=25),
-                             controller=ctl, backend="jax")
-        t0 = time.perf_counter()
-        eng.run(trace)
-        compile_wall = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        eng.run(trace)
-        wall = time.perf_counter() - t0
-        rate = 512 / wall
-        stats["batch_jax_512"] = {
-            "designs": 512, "wall_seconds": wall,
-            "compile_plus_run_seconds": compile_wall,
-            "survivors_per_sec": rate,
-            "speedup_vs_sequential": rate / seq_rate}
-        rows.append(("sim_batch_jax_B512", wall / 512 * 1e6,
-                     f"{rate:,.1f} survivors/s "
-                     f"({rate / seq_rate:.1f}x sequential, "
-                     f"compile {compile_wall:.1f}s)"))
-    except Exception as e:  # pragma: no cover - jax optional at bench time
-        stats["batch_jax_512"] = {"error": repr(e)}
-        rows.append(("sim_batch_jax_B512", 0.0, f"SKIPPED:{e!r}"))
+    idx = survivors[:512]
+    bplat = BatchSimPlatform.from_design_points(m, res, idx, req_mb=REQ_MB)
+    ctl = BatchControllerHarness(bplat.islands, bplat.rates,
+                                 BatchPIDRatePolicy(target=0.7),
+                                 tile_names=bplat.names,
+                                 queue_guard_ticks=3.0)
+    eng = BatchSimEngine(bplat, config=SimConfig(control_interval=25),
+                         controller=ctl, backend="jax")
+    t0 = time.perf_counter()
+    eng.run(trace)
+    compile_wall = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    eng.run(trace)
+    wall = time.perf_counter() - t0
+    rate = 512 / wall
+    stats["batch_jax_512"] = {
+        "designs": 512, "wall_seconds": wall,
+        "compile_plus_run_seconds": compile_wall,
+        "survivors_per_sec": rate,
+        "speedup_vs_sequential": rate / seq_rate}
+    rows.append(("sim_batch_jax_B512", wall / 512 * 1e6,
+                 f"{rate:,.1f} survivors/s "
+                 f"({rate / seq_rate:.1f}x sequential, "
+                 f"compile {compile_wall:.1f}s)"))
 
-    # Pallas fused-tick backend (interpret mode on CPU): a validation
-    # row, not a speed row — interpret mode runs the kernel body under
-    # the Pallas interpreter, so B is kept small and the interesting
-    # number is agreement with the numpy reference, which the engine's
-    # differential tests assert bit-tightly.
-    try:
-        PB = 64
-        idx = survivors[:PB]
-        bplat = BatchSimPlatform.from_design_points(m, res, idx,
-                                                    req_mb=REQ_MB)
-        ctl = BatchControllerHarness(bplat.islands, bplat.rates,
-                                     BatchPIDRatePolicy(target=0.7),
-                                     tile_names=bplat.names,
-                                     queue_guard_ticks=3.0)
-        eng = BatchSimEngine(bplat, config=SimConfig(control_interval=25),
-                             controller=ctl, backend="pallas")
-        t0 = time.perf_counter()
-        rp = eng.run(trace)
-        pallas_wall = time.perf_counter() - t0
-        stats["batch_pallas_64"] = {
-            "designs": PB, "wall_seconds": pallas_wall,
-            "survivors_per_sec": PB / pallas_wall,
-            "mode": "interpret",
-            "completed_total": float(np.sum(rp.completed))}
-        rows.append(("sim_batch_pallas_B64", pallas_wall / PB * 1e6,
-                     f"{PB / pallas_wall:,.1f} survivors/s "
-                     f"(fused tick kernel, interpret mode)"))
-    except Exception as e:  # pragma: no cover - pallas optional at bench
-        stats["batch_pallas_64"] = {"error": repr(e)}
-        rows.append(("sim_batch_pallas_B64", 0.0, f"SKIPPED:{e!r}"))
+    # Pallas fused-tick backend: a validation row, not a speed row — on
+    # the CPU the kernel body runs under the Pallas interpreter, so B is
+    # kept small and the interesting number is agreement with the numpy
+    # reference, which the engine's differential tests assert tightly.
+    from repro.kernels.tick_sim import interpret_mode
+    mode = "interpret" if interpret_mode() else "compiled"
+    PB = 64
+    idx = survivors[:PB]
+    bplat = BatchSimPlatform.from_design_points(m, res, idx, req_mb=REQ_MB)
+    ctl = BatchControllerHarness(bplat.islands, bplat.rates,
+                                 BatchPIDRatePolicy(target=0.7),
+                                 tile_names=bplat.names,
+                                 queue_guard_ticks=3.0)
+    eng = BatchSimEngine(bplat, config=SimConfig(control_interval=25),
+                         controller=ctl, backend="pallas")
+    t0 = time.perf_counter()
+    rp = eng.run(trace)
+    pallas_wall = time.perf_counter() - t0
+    stats["batch_pallas_64"] = {
+        "designs": PB, "wall_seconds": pallas_wall,
+        "survivors_per_sec": PB / pallas_wall,
+        "mode": mode,
+        "completed_total": float(np.sum(rp.completed))}
+    rows.append(("sim_batch_pallas_B64", pallas_wall / PB * 1e6,
+                 f"{PB / pallas_wall:,.1f} survivors/s "
+                 f"(fused tick kernel, {mode})"))
 
     from benchmarks.run import append_bench_row
     append_bench_row(BENCH_JSON, {

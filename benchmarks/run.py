@@ -230,6 +230,8 @@ def main(argv=None) -> None:
                     help="overwrite --out even if its schema is foreign")
     args = ap.parse_args(argv)
     check_out_target(args.out, force=args.force)
+    from repro.shard import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (bench_contention, bench_dfs_traffic, bench_dse,
                             bench_kernels, bench_observe, bench_replication,

@@ -46,7 +46,7 @@ ENTRY_ARG_POSITIONS: Dict[str, Tuple[int, ...]] = {
 # dotted prefixes that mark a callable as "traces its argument" — a bare
 # last-component match alone is not enough for common words like "scan"
 _JAXISH_ROOTS = ("jax", "jax.numpy", "jax.lax", "jax.experimental",
-                 "repro.compat", "functools.partial")
+                 "functools.partial")
 # last components accepted even without a jax-ish root (their names are
 # unambiguous in this codebase)
 _ALWAYS_ENTRY = {"pallas_call", "shard_map"}
@@ -103,7 +103,7 @@ def dotted_name(node: ast.AST) -> Optional[str]:
 
 class ImportMap:
     """alias -> fully dotted origin (``pl`` ->
-    ``jax.experimental.pallas``, ``_smap`` -> ``repro.compat.shard_map``,
+    ``jax.experimental.pallas``, ``_smap`` -> ``jax.shard_map``,
     ``np`` -> ``numpy``)."""
 
     def __init__(self, tree: ast.Module):

@@ -603,7 +603,6 @@ def _flat_point_evaluator(n_devices: int, A: int, n_tg: int,
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
     from repro import shard as shard_mod
     from jax.sharding import PartitionSpec
 
@@ -647,7 +646,7 @@ def _flat_point_evaluator(n_devices: int, A: int, n_tg: int,
     mesh = shard_mod.device_mesh(n_devices, "points")
     s2 = PartitionSpec(None, "points")
     s1 = PartitionSpec("points")
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(s2, s2, s2) + (s1,) * (n_in - 3),
         out_specs=(s1, s1, s1), check_vma=False))
 

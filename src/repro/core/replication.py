@@ -59,9 +59,11 @@ def make_mra_mesh(k: int, *, multi_pod: bool = False,
     assert model % k == 0, (model, k)
     if multi_pod:
         return jax.make_mesh((2, data, k, model // k),
-                             ("pod", "data", "replica", "shard"))
+                             ("pod", "data", "replica", "shard"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 4)
     return jax.make_mesh((data, k, model // k),
-                         ("data", "replica", "shard"))
+                         ("data", "replica", "shard"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 
 def mra_rules(plan: TilePlan, mesh: Mesh) -> Dict[str, Dict[str, Axis]]:

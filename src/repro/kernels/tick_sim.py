@@ -12,8 +12,8 @@ the whole tick into ONE Pallas body:
   scratch across all ``T`` steps of a design block and HBM sees each
   arrival tile exactly once (the flash-attention/ssd-scan block idiom).
 * per-design constants (``base``, ``req``, ``k``, ``inc``...) stream in
-  as ``(bB, ...)`` blocks indexed by the design-block grid dim; shared
-  per-tick scalars (the control-cadence flag) ride a ``(T, 1)`` input.
+  as ``(bB, ...)`` blocks indexed by the design-block grid dim; the
+  control-cadence flag is computed from the tick index.
 * Pallas kernels cannot close over array constants ("captures constants
   ... pass them as inputs"), so every design-independent array — the
   tile→island one-hot, a vector flow demand, the forward coupling
@@ -35,8 +35,9 @@ under jax's default x64-off config); differential tests compare against
 both the scan backend (tight f32 tolerance) and the NumPy float64 engine
 (looser tolerance).
 
-CPU path: ``interpret=True`` (the default) runs the kernel through the
-Pallas interpreter so the differential suite runs everywhere.
+The platform picks the mode (:func:`interpret_mode`): a TPU compiles the
+kernel, the CPU runs it through the Pallas interpreter so the
+differential suite runs everywhere.
 """
 from __future__ import annotations
 
@@ -50,9 +51,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.perfmodel import P_DYN_W, P_STATIC_W, V_BASE, V_SLOPE
+from repro.sim.batch import HIGHEST
 
-_N_IN_FIXED = 13   # arr, isctl, base, req, w, k, hop, tcr, inc, ftg,
-#                    iotM, rates0, guard0
+_N_IN_FIXED = 12   # arr, base, req, w, k, hop, tcr, inc, ftg, iotM,
+#                    rates0, guard0
 
 
 def _v2(f):
@@ -65,7 +67,7 @@ def _tick_kernel(*refs, n_pol, n_extra, extra_keys, extra_bool,
                  hop_lat, hop_share, hopf0, noc_share, n_tg, dyn_on,
                  max_q, ci, noc_idx, demand_scalar, has_fwd,
                  tech_on, t_ps, t_v0, t_v1):
-    (arr_ref, isctl_ref, base_ref, req_ref, w_ref, k_ref, hop_ref,
+    (arr_ref, base_ref, req_ref, w_ref, k_ref, hop_ref,
      tcr_ref, inc_ref, ftg_ref, iotM_ref, rates0_ref,
      guard0_ref) = refs[:_N_IN_FIXED]
     pol0_refs = refs[_N_IN_FIXED:_N_IN_FIXED + n_pol]
@@ -106,7 +108,8 @@ def _tick_kernel(*refs, n_pol, n_extra, extra_keys, extra_bool,
             p_s[...] = p0_ref[...]
 
     rates = ra_s[...]                                       # (bB, I)
-    f_tile = rates @ iotM_ref[...]                          # (bB, A)
+    f_tile = jnp.dot(rates, iotM_ref[...],                  # (bB, A)
+                     precision=HIGHEST)
     f_noc = (rates[:, noc_idx] if noc_idx >= 0
              else jnp.ones(rates.shape[0], rates.dtype))
     fa = jnp.maximum(f_tile, 1e-3)
@@ -133,7 +136,9 @@ def _tick_kernel(*refs, n_pol, n_extra, extra_keys, extra_bool,
     busy_prev = b_s[...]
     if dyn_on:
         inc = inc_ref[...]                                  # (bB, A, L)
-        loads = jnp.einsum("ba,bal->bl", demand * busy_prev, inc)
+        # a batched contraction has no Mosaic dot lowering: multiply and
+        # reduce over the tile axis instead
+        loads = ((demand * busy_prev)[:, :, None] * inc).sum(axis=1)
         rho = (inc * loads[:, None, :]).max(axis=-1) / (link_bw * fn)
         r = jnp.minimum(rho, 0.999)
         dyn = jnp.minimum(1.0 + r / (2.0 * (1.0 - r)), max_slow)
@@ -146,7 +151,8 @@ def _tick_kernel(*refs, n_pol, n_extra, extra_keys, extra_bool,
     busy = served / cap
     rt_s[...] += hop_ref[...] * dyn * hop_lat
     if has_fwd:
-        fw_s[...] = jnp.einsum("ba,aj->bj", served, fwd)
+        fw_s[...] = jnp.einsum("ba,aj->bj", served, fwd,
+                               precision=HIGHEST)
 
     fnr = f_noc[:, None]                # unclamped, as the scan backend
     if tech_on:
@@ -162,7 +168,7 @@ def _tick_kernel(*refs, n_pol, n_extra, extra_keys, extra_bool,
     en_s[...] += (tp.sum(axis=-1, keepdims=True) + noc_p) * dt
     ctl_busy = cb_s[...] + busy
 
-    ctl_flag = isctl_ref[0, 0] > 0.5
+    ctl_flag = (t + 1) % ci == 0 if ci else np.bool_(False)
     if control_fn is not None:
         t_wire_now = t_wire * dyn
         obs = {"util": ctl_busy / max(ci, 1),
@@ -202,23 +208,55 @@ def _tick_kernel(*refs, n_pol, n_extra, extra_keys, extra_bool,
             pf_ref[...] = p_s[...]
 
 
-def fused_tick_sim(arrivals, is_ctl, consts, scalars, init, *,
+def interpret_mode() -> bool:
+    """Whether the kernel runs through the Pallas interpreter, chosen from
+    the platform it runs on: the CPU interprets, a TPU compiles, and any
+    other platform has no lowering of this kernel."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise NotImplementedError(
+        f"the fused tick kernel has no lowering for {platform!r}")
+
+
+def fused_tick_sim(arrivals, consts, scalars, init, *,
                    control_fn: Optional[Callable] = None,
                    control_consts=None,
-                   block_b: Optional[int] = None,
-                   interpret: bool = True):
-    """Run ``T`` fused simulator ticks over a ``(T, B, A)`` arrival tensor.
+                   block_b: Optional[int] = None):
+    """Run ``T`` fused simulator ticks over a ``(T, B, A)`` arrival tensor
+    (see :func:`tick_kernel_call` for the arguments), compiled on a TPU
+    and interpreted on the CPU.  Returns a dict of f32 outputs
+    (``adm``/``served`` histories, final state, accumulators, evolved
+    control state) sliced back to the true ``B``."""
+    call, inputs, unpack = tick_kernel_call(
+        arrivals, consts, scalars, init, control_fn=control_fn,
+        control_consts=control_consts, block_b=block_b,
+        interpret=interpret_mode())
+    return unpack(call(*inputs))
+
+
+def tick_kernel_call(arrivals, consts, scalars, init, *,
+                     control_fn: Optional[Callable] = None,
+                     control_consts=None,
+                     block_b: Optional[int] = None,
+                     interpret: bool):
+    """Build the tick kernel's ``pallas_call`` without running it.
 
     ``consts``: per-design arrays — ``base``/``req``/``w``/``k``/``hop``/
     ``tcr`` ``(B, A)``, ``inc`` ``(B, A, L)``, ``ftg`` ``(B, 1)``.
     ``scalars``: python-level model/config constants (baked into the
-    kernel), including ``iot``/``noc_idx``/``demand``/``forward``.
-    ``init``: ``rates``/``guard`` ``(B, I)`` plus a ``pol`` tuple of
-    B-leading 2-D policy-state arrays.  ``control_consts``: the numpy
-    topology tables the control lowering needs (re-injected through its
-    ``consts=`` kwarg; required when ``control_fn`` is set).  Returns a
-    dict of f32 outputs (``adm``/``served`` histories, final state,
-    accumulators, evolved control state) sliced back to the true ``B``.
+    kernel), including ``iot``/``noc_idx``/``demand``/``forward`` and the
+    control cadence ``ci`` (control runs on ticks ``t`` with
+    ``(t + 1) % ci == 0``).  ``init``: ``rates``/``guard`` ``(B, I)`` plus
+    a ``pol`` tuple of B-leading 2-D policy-state arrays.
+    ``control_consts``: the numpy topology tables the control lowering
+    needs (re-injected through its ``consts=`` kwarg; required when
+    ``control_fn`` is set).
+
+    Returns ``(call, inputs, unpack)``: ``call(*inputs)`` runs the kernel
+    and ``unpack`` turns its outputs into :func:`fused_tick_sim`'s dict.
     """
     arrivals = np.asarray(arrivals, dtype=np.float32)
     T, B, A = arrivals.shape
@@ -266,7 +304,6 @@ def fused_tick_sim(arrivals, is_ctl, consts, scalars, init, *,
 
     inputs = [
         padded(arrivals, axis=1),
-        np.asarray(is_ctl, dtype=np.float32).reshape(T, 1),
         padded(consts["base"]), padded(consts["req"]),
         padded(consts["w"]), padded(consts["k"]),
         padded(consts["hop"]), padded(consts["tcr"]),
@@ -286,7 +323,6 @@ def fused_tick_sim(arrivals, is_ctl, consts, scalars, init, *,
 
     in_specs = [
         blk((1, bB, A), lambda b, t: (t, b, 0)),        # arr
-        blk((1, 1), lambda b, t: (t, 0)),               # isctl
     ] + [blk((bB, A), lambda b, t: (b, 0))] * 6 + [     # base..tcr
         blk((bB, A, L), lambda b, t: (b, 0, 0)),        # inc
         blk((bB, 1), lambda b, t: (b, 0)),              # ftg
@@ -347,27 +383,30 @@ def fused_tick_sim(arrivals, is_ctl, consts, scalars, init, *,
         t_ps=float(scalars.get("t_ps", 1.0)),
         t_v0=float(scalars.get("t_v0", V_BASE)),
         t_v1=float(scalars.get("t_v1", V_SLOPE)))
-    outs = pl.pallas_call(
+    call = pl.pallas_call(
         kernel, grid=(nb, T), in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret)(*inputs)
+        interpret=interpret)
 
-    (adm, served, queue, busy, rtt, rates, guard, dropped, energy,
-     swaps) = outs[:10]
-    polF = tuple(
-        (np.asarray(p)[:B] > 0.5) if np.issubdtype(dtp, np.bool_)
-        else np.asarray(p)[:B].astype(dtp)
-        for p, dtp in zip(outs[10:], pol_dtypes))
-    return {
-        "adm": np.asarray(adm)[:, :B],
-        "served": np.asarray(served)[:, :B],
-        "queue": np.asarray(queue)[:B],
-        "busy": np.asarray(busy)[:B],
-        "rtt": np.asarray(rtt)[:B],
-        "rates": np.asarray(rates)[:B],
-        "guard": np.asarray(guard)[:B] > 0.5,
-        "dropped": np.asarray(dropped)[:B, 0],
-        "energy": np.asarray(energy)[:B, 0],
-        "swaps": np.asarray(swaps)[:B, 0],
-        "pol": polF,
-    }
+    def unpack(outs):
+        (adm, served, queue, busy, rtt, rates, guard, dropped, energy,
+         swaps) = outs[:10]
+        polF = tuple(
+            (np.asarray(p)[:B] > 0.5) if np.issubdtype(dtp, np.bool_)
+            else np.asarray(p)[:B].astype(dtp)
+            for p, dtp in zip(outs[10:], pol_dtypes))
+        return {
+            "adm": np.asarray(adm)[:, :B],
+            "served": np.asarray(served)[:, :B],
+            "queue": np.asarray(queue)[:B],
+            "busy": np.asarray(busy)[:B],
+            "rtt": np.asarray(rtt)[:B],
+            "rates": np.asarray(rates)[:B],
+            "guard": np.asarray(guard)[:B] > 0.5,
+            "dropped": np.asarray(dropped)[:B, 0],
+            "energy": np.asarray(energy)[:B, 0],
+            "swaps": np.asarray(swaps)[:B, 0],
+            "pol": polF,
+        }
+
+    return call, inputs, unpack

@@ -36,7 +36,6 @@ from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.replication import make_mra_mesh
 from repro.core.tiles import default_plan
 from repro.launch import specs as SP
-from repro.compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.models.layers import AttnOptions
 from repro.models.params import abstract_params
@@ -179,7 +178,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
                      SP.batch_shardings(batch_abs, mesh, extra),
                      SP.counter_shardings(ctr_abs, mesh))
             fn = jax.jit(step, in_shardings=in_sh, donate_argnums=(0, 1, 3))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = fn.lower(params_abs, opt_abs, batch_abs, ctr_abs)
         elif shape.kind == "prefill":
             tok_abs = SP.abstract_prefill_tokens(shape)
@@ -187,7 +186,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
                          in_shardings=(param_sh,
                                        SP.batch_shardings(tok_abs, mesh,
                                                           extra)))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = fn.lower(params_abs, tok_abs)
         else:  # decode
             cache_abs, tok_abs = SP.abstract_decode_inputs(lm, shape)
@@ -196,7 +195,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
                          in_shardings=(param_sh, cache_sh,
                                        SP.batch_shardings(tok_abs, mesh)),
                          donate_argnums=(1,))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = fn.lower(params_abs, cache_abs, tok_abs)
 
         meta = {"arch": arch, "shape": shape_name, "kind": shape.kind,

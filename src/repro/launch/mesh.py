@@ -19,11 +19,13 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist right now, as a 1D (data,) mesh — for local
     examples and tests that want a real (non-dry-run) mesh."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))

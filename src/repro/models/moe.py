@@ -24,8 +24,6 @@ from repro.configs.base import ArchConfig
 from repro.models.params import spec, get_batch_axes
 from repro.models.layers import _act, DATA, MODEL
 
-from repro.compat import get_abstract_mesh as _get_abstract_mesh
-from repro.compat import shard_map as _shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -164,7 +162,7 @@ def moe_apply(p: Dict, cfg: ArchConfig, x: jax.Array,
 
     if mesh is None:
         try:
-            mesh = _get_abstract_mesh()
+            mesh = jax.sharding.get_abstract_mesh()
         except Exception:  # pragma: no cover
             mesh = None
     names = tuple(getattr(mesh, "axis_names", ()) or ())
@@ -212,7 +210,7 @@ def moe_apply(p: Dict, cfg: ArchConfig, x: jax.Array,
             routed_c = {k: jax.lax.with_sharding_constraint(v, ep_specs[k])
                         for k, v in routed_p.items()}
             xf_c = jax.lax.with_sharding_constraint(xf, P(all_axes, None))
-            out, aux = _shard_map(
+            out, aux = jax.shard_map(
                 ep_body, mesh=mesh,
                 in_specs=({k: ep_specs[k] for k in routed_p},
                           P(all_axes, None)),
@@ -250,7 +248,7 @@ def moe_apply(p: Dict, cfg: ArchConfig, x: jax.Array,
                 aux = jax.lax.pmean(aux, dp)
             return out, aux
 
-        out, aux = _shard_map(
+        out, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=({k: specs[k] for k in routed_p}, P(tok, None)),
             out_specs=(P(tok, None), P()),

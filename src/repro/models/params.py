@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import get_abstract_mesh as _ambient_mesh
 
 Axis = Optional[Union[str, Tuple[str, ...]]]
 
@@ -192,7 +191,7 @@ def shard_activation(x: jax.Array, *axes: Axis) -> jax.Array:
     axes = tuple(_BATCH_AXES if a == _DEFAULT_BATCH_AXES else a
                  for a in axes)
     try:
-        _names = set(_ambient_mesh().axis_names)
+        _names = set(jax.sharding.get_abstract_mesh().axis_names)
     except Exception:                                    # pragma: no cover
         _names = set()
     if "model" not in _names and "shard" in _names:
@@ -209,7 +208,7 @@ def shard_activation(x: jax.Array, *axes: Axis) -> jax.Array:
     else:
         axes = tuple("model" if a == "__model_full__" else a for a in axes)
     try:
-        am = _ambient_mesh()
+        am = jax.sharding.get_abstract_mesh()
     except Exception:                                    # pragma: no cover
         return x
     if am is None or not getattr(am, "axis_names", ()):  # no mesh context

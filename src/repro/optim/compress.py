@@ -21,7 +21,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -59,6 +58,6 @@ def compressed_allreduce(grads: Any, mesh, axis: str = "pod") -> Any:
 
     # every leaf fully replicated within the pod slice; sharded over axis
     spec = P()   # logical view: identical shapes per pod; axis is vmapped
-    return _shard_map(body, mesh=mesh,
-                      in_specs=(spec,), out_specs=spec,
-                      check_vma=False)(grads)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(grads)

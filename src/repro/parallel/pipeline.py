@@ -32,7 +32,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -84,7 +83,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
 
     params_specs = jax.tree_util.tree_map(
         lambda a: P(axis), stage_params)
-    out = _shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(params_specs, P()),
         out_specs=P(),

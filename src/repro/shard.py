@@ -3,12 +3,11 @@
 The chunked ``grid_sweep`` evaluator and the ``BatchSimEngine`` design
 batch are embarrassingly parallel along one axis (flat design points,
 the B design axis).  This module owns the small amount of mesh plumbing
-both need to run that axis through ``shard_map`` via the version shims
-in :mod:`repro.compat`:
+both need to run that axis through ``jax.shard_map``:
 
 * :func:`resolve_devices` — turn a ``devices=`` knob (``None`` / int /
-  ``"auto"``) into a concrete device count, clamped to what the jax
-  runtime actually exposes.  Multi-device CPU runs come from
+  ``"auto"``) into a concrete device count, refusing more devices than
+  the jax runtime exposes.  Multi-device CPU runs come from
   ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before the
   first jax import; the distributed tests spawn subprocesses for this).
 * :func:`device_mesh` — a cached 1-D :class:`jax.sharding.Mesh` over the
@@ -19,6 +18,8 @@ in :mod:`repro.compat`:
 * :func:`pad_axis` / :func:`shard_len` — pad an array so an axis splits
   evenly across devices (padded tail rows are computed and discarded —
   every sharded caller slices results back to the true length).
+* :func:`enable_compile_cache` — JAX's persistent compilation cache at a
+  fixed path, for the entry points that run on an accelerator.
 
 Correctness contract: sharding only *partitions* an elementwise (or
 per-design-independent) computation, so any device count — including 1 —
@@ -28,6 +29,7 @@ tested against it (``tests/test_shard_pallas.py``).
 """
 from __future__ import annotations
 
+import os
 from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
@@ -38,6 +40,12 @@ DEFAULT_AXIS = "shard"
 # actually used in this process — never keyed on arrays or configs
 _MESH_CACHE: Dict[Tuple[int, str], object] = {}
 _MESH_CACHE_MAX = 32
+
+# <repo>/.jax_cache: a fixed path, so a second run of one checkout finds
+# what the first one compiled
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def device_count() -> int:
@@ -50,9 +58,8 @@ def resolve_devices(devices: Union[None, int, str]) -> int:
     """Normalize a ``devices=`` knob to a concrete count.
 
     ``None`` -> 1 (sharding off, the ground-truth single-device path);
-    ``"auto"`` -> every visible device; an int is clamped to the visible
-    device count (asking for 8 on a 1-device runtime runs unsharded
-    rather than failing — the knob expresses intent, the runtime decides).
+    ``"auto"`` -> every visible device; an int is taken as given, and
+    asking for more devices than are visible raises ``ValueError``.
     """
     if devices is None:
         return 1
@@ -61,7 +68,25 @@ def resolve_devices(devices: Union[None, int, str]) -> int:
         return n
     d = int(devices)
     assert d >= 1, f"devices={devices!r}"
-    return min(d, n)
+    if d > n:
+        raise ValueError(
+            f"devices={d} asked for, but jax sees only {n} device(s)")
+    return d
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Call before the first compilation.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def device_mesh(n_devices: int, axis_name: str = DEFAULT_AXIS):

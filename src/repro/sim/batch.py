@@ -35,15 +35,15 @@ Three backends: ``"numpy"`` (float64, the ground-truth reference),
 unless ``jax_enable_x64``), so the whole grid_sweep -> Pareto -> batched
 co-sim pipeline can run jitted end to end — and ``"pallas"``, the
 queue-update/service/forward tick sequence fused into one Pallas kernel
-(:mod:`repro.kernels.tick_sim`; ``interpret=True`` everywhere a real
-TPU is absent).  The jax backend supports open-loop replay, the
+(:mod:`repro.kernels.tick_sim`; compiled on a TPU, interpreted on the
+CPU).  The jax backend supports open-loop replay, the
 vectorized membound/PID policies (+ queue guard), *custom* jax-side
 batch policies (any policy exposing the ``jax_step`` protocol — see
 :meth:`BatchSimEngine._control_plan`), flow patterns, per-design traces
 and the balancer; it records no telemetry rings (latency percentiles
 are still reconstructed exactly from the returned histories).  With
 ``devices=`` the jax backend shards the design axis across devices via
-``shard_map`` (``repro.shard`` + the ``repro.compat`` shims): the
+``jax.shard_map`` (mesh plumbing in ``repro.shard``): the
 per-design rows are fully independent, so any device count returns the
 single-device floats exactly (differentially tested) — spin up virtual
 CPU devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
@@ -72,6 +72,10 @@ from repro.sim.flows import FlowPattern, compile_flows
 from repro.sim.observe import STALL_EPS, CounterPlane, Observer
 from repro.sim.telemetry import BatchTelemetry, TelemetrySchema
 from repro.sim.traffic import BatchTrace, Trace
+
+# every contraction of the jax backends runs at full f32 precision: a
+# TPU's default rounds f32 matmul operands to bfloat16
+HIGHEST = "highest"
 
 # jitted-scan LRU bound: one compiled executable per distinct
 # (trace shape, cadence, fault class, policy/balancer/config digest);
@@ -819,6 +823,13 @@ class BatchSimEngine:
         else:
             pol_state0 = ()
 
+        def on_ctl(ctl_flag, new, old):  # repro: traced
+            # where(ctl_flag, new, old); Mosaic cannot select between
+            # boolean vectors, so booleans take the and/or form
+            if new.dtype == jnp.bool_:
+                return (ctl_flag & new) | (~ctl_flag & old)
+            return jnp.where(ctl_flag, new, old)
+
         def control(rates, guard, pol_state, ctl_flag, obs,  # repro: traced
                     dead=None, stuck=None, consts=None):
             c = (consts if consts is not None
@@ -829,8 +840,10 @@ class BatchSimEngine:
             fixed = c["fixed"]
             levels = c["levels"]
             skip = c["skip"]
-            util_i = (obs["util"] @ membership.T) / counts_safe
-            bound_i = (obs["bound"] @ membership.T) / counts_safe
+            util_i = jnp.dot(obs["util"], membership.T,
+                             precision=HIGHEST) / counts_safe
+            bound_i = jnp.dot(obs["bound"], membership.T,
+                              precision=HIGHEST) / counts_safe
             qt = obs["qt"]
             qt_i = jnp.where(membership[None, :, :] > 0,
                              qt[:, None, :], -jnp.inf).max(axis=-1)
@@ -854,8 +867,8 @@ class BatchSimEngine:
                        + plan["kd"] * d_term)
                 req = jnp.clip(new, plan["min_rate"], 1.0)
                 valid = ~skip[None, :] & jnp.ones_like(valid)
-                pol_state = (jnp.where(ctl_flag, i_term, pid_i),
-                             jnp.where(ctl_flag, err, pid_prev),
+                pol_state = (on_ctl(ctl_flag, i_term, pid_i),
+                             on_ctl(ctl_flag, err, pid_prev),
                              pid_has | ctl_flag)
             elif kind == "custom":
                 obs_i = {"util": util_i, "boundness": bound_i,
@@ -868,20 +881,20 @@ class BatchSimEngine:
                 req = jnp.where(valid, req_raw, rates)
                 pol_state = tuple(
                     jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(ctl_flag, n, o),
+                        lambda n, o: on_ctl(ctl_flag, n, o),
                         tuple(new_state), tuple(pol_state)))
 
             if plan["guard"] is not None:
-                latch = jnp.where(
-                    qt_i > plan["guard"], True,
-                    jnp.where(qt_i < plan["guard_release"], False,
-                              guard))
+                # where(qt > guard, True, where(qt < release, False,
+                # guard)) in boolean algebra (see on_ctl)
+                latch = ((qt_i > plan["guard"])
+                         | (~(qt_i < plan["guard_release"]) & guard))
                 latch = latch & ~fixed[None, :]
                 if dead is not None:     # dead islands drop out of latch
                     latch = latch & ~dead[None, :]
                 req = jnp.where(latch, plan["guard_rate"], req)
                 valid = valid | latch
-                guard = jnp.where(ctl_flag, latch, guard)
+                guard = on_ctl(ctl_flag, latch, guard)
 
             if tech_lo is not None:
                 # clamp commits into the node's legal DVFS ratio range
@@ -891,17 +904,20 @@ class BatchSimEngine:
             d = jnp.abs(levels[None, :, :] - req[:, :, None])
             if tech_lo is not None:     # illegal levels can't win argmin
                 d = jnp.where(c["tech_legal"][None, :, :], d, jnp.inf)
-            idx = jnp.argmin(d, axis=-1)
-            qz = jnp.take_along_axis(
-                jnp.broadcast_to(levels, (req.shape[0],) + levels.shape),
-                idx[:, :, None], axis=-1)[:, :, 0]
+            # nearest level, first on ties (argmin's rule), as a one-hot
+            # select: Mosaic lowers no 3-D gather
+            pos = jax.lax.broadcasted_iota(jnp.int32, d.shape, 2)
+            first = jnp.where(d == d.min(axis=-1, keepdims=True), pos,
+                              d.shape[-1]).min(axis=-1, keepdims=True)
+            qz = jnp.where(pos == first, levels[None, :, :],
+                           -jnp.inf).max(axis=-1)
             changed = valid & ~fixed[None, :] & (qz != rates) & ctl_flag
             if dead is not None:        # no hardware to commit to
                 changed = changed & ~dead[None, :]
             if stuck is not None:       # actuator write never lands
                 changed = changed & ~stuck[None, :]
             rates = jnp.where(changed, qz, rates)
-            committed = jnp.where(ctl_flag, changed.any(axis=-1), False)
+            committed = changed.any(axis=-1)    # changed implies ctl_flag
             return rates, guard, pol_state, committed
 
         return control, pol_state0, cst
@@ -1057,12 +1073,12 @@ class BatchSimEngine:
                 w = jnp.where(jnp.isfinite(w) & (w > 0.0), w, 0.0)
                 if alive is not None:
                     w = w * alive
-                tot = jnp.einsum("ba,ga->bg", arr, lbM)
-                wsum = jnp.einsum("ba,ga->bg", w, lbM)
+                tot = jnp.einsum("ba,ga->bg", arr, lbM, precision=HIGHEST)
+                wsum = jnp.einsum("ba,ga->bg", w, lbM, precision=HIGHEST)
                 # all-zero weight groups fall back to an even split,
                 # mirroring LoadBalancer.split
                 w = jnp.where((wsum <= 0.0)[:, lb_gof], 1.0, w)
-                wsum = jnp.einsum("ba,ga->bg", w, lbM)
+                wsum = jnp.einsum("ba,ga->bg", w, lbM, precision=HIGHEST)
                 shared = tot[:, lb_gof] * (w / wsum[:, lb_gof])
                 return jnp.where(lb_cov, shared, arr)
         tgd = m.tg_demand
@@ -1172,7 +1188,8 @@ class BatchSimEngine:
                     queue = queue - stranded
                     retry_q = retry_q - s_retry
                     if recover:
-                        surv = jnp.einsum("a,ga->g", alive_t, lbM) > 0.0
+                        surv = jnp.einsum("a,ga->g", alive_t, lbM,
+                                          precision=HIGHEST) > 0.0
                         can = lb_cov & surv[lb_gof]
                         respill = jnp.where(can, stranded - s_retry, 0.0)
                         fdrop = stranded - respill
@@ -1205,7 +1222,8 @@ class BatchSimEngine:
                     adm = adm - over
                     dropped = dropped + over.sum(axis=-1)
                 if dyn_on:
-                    loads = jnp.einsum("ba,bal->bl", demand * busy, inc)
+                    loads = jnp.einsum("ba,bal->bl", demand * busy, inc,
+                                       precision=HIGHEST)
                     if has_link:
                         loads = loads / xs["lscale"]
                     rho = ((inc * loads[:, None, :]).max(axis=-1)
@@ -1244,7 +1262,8 @@ class BatchSimEngine:
                         0.0)
                 rtt = rtt + hop_counts * dyn * hop_lat
                 if has_fwd:
-                    carry_fwd = jnp.einsum("ba,aj->bj", served, fwdM)
+                    carry_fwd = jnp.einsum("ba,aj->bj", served, fwdM,
+                                           precision=HIGHEST)
                 if lb is not None:
                     prev_cap = cap
 
@@ -1354,7 +1373,6 @@ class BatchSimEngine:
             if D <= 1:
                 return jax.jit(run_scan)
             from jax.sharding import PartitionSpec
-            from repro.compat import shard_map as _smap
             mesh = shard_mod.device_mesh(D, "designs")
 
             def lead(a):
@@ -1387,8 +1405,10 @@ class BatchSimEngine:
                         *((None, "designs")
                           + (None,) * (len(s.shape) - 2))),
                     out_sh[1]))
-            return jax.jit(_smap(run_scan, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False))
+            return jax.jit(jax.shard_map(run_scan, mesh=mesh,
+                                         in_specs=in_specs,
+                                         out_specs=out_specs,
+                                         check_vma=False))
 
         fn = self._cached_scan(sig, build)
 
@@ -1520,20 +1540,11 @@ class BatchSimEngine:
             qdrops=qdrops)
 
     # ------------------------------------------------------------ pallas
-    def _run_pallas(self, trace) -> BatchSimResult:
-        """The fused-kernel backend: the whole queue-update / contention /
-        service / forward / control tick as ONE Pallas kernel
-        (:func:`repro.kernels.tick_sim.fused_tick_sim`), T grid steps
-        deep, per-tile state held in VMEM scratch between ticks.
-
-        Scope: open-loop replay + every controller the jax backend's
-        control lowering supports (membound / PID / guard / custom
-        ``jax_step`` policies).  Faults, SLO semantics, the load
-        balancer and the observer plane need scan-side bookkeeping this
-        kernel does not carry — those runs raise ``NotImplementedError``
-        and belong on ``backend="jax"``.  Differentially validated
-        against the NumPy float64 engine (f32 tolerance) and the scan
-        backend."""
+    def _pallas_args(self, trace):
+        """Check the run is in the kernel's scope and assemble the keyword
+        arguments of :func:`repro.kernels.tick_sim.fused_tick_sim`
+        (equally of ``tick_kernel_call``, which builds the kernel without
+        running it).  Returns ``(args, plan, swaps_before)``."""
         p, cfg = self.platform, self.config
         B, A, T, dt = p.n_designs, p.n_tiles, trace.ticks, trace.dt
         self._check_trace(trace)
@@ -1553,7 +1564,6 @@ class BatchSimEngine:
             raise NotImplementedError(
                 "pallas backend records no observer plane; "
                 "use backend='jax' or 'numpy'")
-        from repro.kernels.tick_sim import fused_tick_sim
 
         m = p.model
         plan = self._control_plan()
@@ -1574,9 +1584,6 @@ class BatchSimEngine:
         arr = np.asarray(trace.arrivals)
         if arr.ndim == 2:               # shared trace -> (T, B, A)
             arr = np.broadcast_to(arr[:, None, :], (T, B, A))
-        is_ctl = np.zeros(T, dtype=bool)
-        if ci:
-            is_ctl[ci - 1::ci] = True
 
         consts = {"base": p.base_mbps, "req": p.req_mb,
                   "w": p.wire_share, "k": p.k,
@@ -1605,11 +1612,30 @@ class BatchSimEngine:
              scalars["t_v1"]) = self.tech.power_coeffs
         init = {"rates": np.asarray(rates0), "guard": np.asarray(guard0),
                 "pol": tuple(pol0)}
+        args = dict(arrivals=arr, consts=consts, scalars=scalars,
+                    init=init, control_fn=control, control_consts=cctl)
+        return args, plan, swaps_before
 
+    def _run_pallas(self, trace) -> BatchSimResult:
+        """The fused-kernel backend: the whole queue-update / contention /
+        service / forward / control tick as ONE Pallas kernel
+        (:func:`repro.kernels.tick_sim.fused_tick_sim`), T grid steps
+        deep, per-tile state held in VMEM scratch between ticks.
+
+        Scope: open-loop replay + every controller the jax backend's
+        control lowering supports (membound / PID / guard / custom
+        ``jax_step`` policies).  Faults, SLO semantics, the load
+        balancer and the observer plane need scan-side bookkeeping this
+        kernel does not carry — those runs raise ``NotImplementedError``
+        and belong on ``backend="jax"``.  Differentially validated
+        against the NumPy float64 engine (f32 tolerance) and the scan
+        backend."""
+        from repro.kernels.tick_sim import fused_tick_sim
+        p = self.platform
+        B, A = p.n_designs, p.n_tiles
+        args, plan, swaps_before = self._pallas_args(trace)
         wall0 = time.perf_counter()
-        out = fused_tick_sim(arr, is_ctl, consts, scalars, init,
-                             control_fn=control, control_consts=cctl,
-                             interpret=True)
+        out = fused_tick_sim(**args)
         admitted = np.asarray(out["adm"], dtype=np.float64)
         served = np.asarray(out["served"], dtype=np.float64)
         queueF = np.asarray(out["queue"], dtype=np.float64)

@@ -37,15 +37,11 @@ def test_sharded_train_step_matches_single_device():
                          warmup_steps=1, total_steps=50))
         kw = dict(lm_kwargs=dict(opts=AttnOptions(backend='naive'),
                                  remat=False), tc=tc)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
-        # compat.set_mesh: jax.set_mesh doesn't exist on the pinned jax
-        # 0.4.x (the seed failure mode of this test was an AttributeError
-        # inside the subprocess, not loss drift); the Mesh context manager
-        # installs the same ambient mesh there.  The residual sharded-vs-
-        # single drift under it is ~2e-3 (f32 collective reduction order),
-        # well inside the 2e-2 gate.
-        from repro.compat import set_mesh
-        with set_mesh(mesh):
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        # the residual sharded-vs-single drift is ~2e-3 (f32 collective
+        # reduction order), well inside the 2e-2 gate
+        with jax.set_mesh(mesh):
             tr_m = Trainer(cfg, shape, mesh=mesh, **kw)
             h_m = tr_m.run(3)
         tr_1 = Trainer(cfg, shape, mesh=None, **kw)
@@ -73,9 +69,9 @@ def test_moe_shard_map_path_matches_local():
         local, aux_l = _moe_ffn_local({k: v for k, v in p.items()
                                        if k != 'shared'},
                                       x.reshape(-1, cfg.d_model), cfg)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
-        from repro.compat import set_mesh
-        with set_mesh(mesh):
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        with jax.set_mesh(mesh):
             out, aux = jax.jit(lambda p, x: moe_apply(p, cfg, x))(p, x)
         ref = local.reshape(x.shape)
         if 'shared' in p:
@@ -96,17 +92,17 @@ def test_compressed_allreduce_pod_axis():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.optim.compress import compressed_psum_leaf
-        from repro.compat import shard_map
         from jax.sharding import PartitionSpec as P
 
-        mesh = jax.make_mesh((2, 4), ('pod', 'data'))
+        mesh = jax.make_mesh((2, 4), ('pod', 'data'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         g = jax.random.normal(jax.random.PRNGKey(0), (2, 64))
 
         def body(x):
             return compressed_psum_leaf(x[0], 'pod')
 
-        out = shard_map(body, mesh=mesh, in_specs=(P('pod', None),),
-                        out_specs=P(None), check_vma=False)(g)
+        out = jax.shard_map(body, mesh=mesh, in_specs=(P('pod', None),),
+                            out_specs=P(None), check_vma=False)(g)
         exact = g.sum(0)
         rel = float(jnp.linalg.norm(out - exact) / jnp.linalg.norm(exact))
         assert rel < 0.02, rel
@@ -123,12 +119,14 @@ def test_elastic_restore_across_meshes(tmp_path):
         from repro.checkpoint.store import CheckpointStore
 
         t = {{'w': jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
-        m1 = jax.make_mesh((2, 4), ('data', 'model'))
+        m1 = jax.make_mesh((2, 4), ('data', 'model'),
+                           axis_types=(jax.sharding.AxisType.Auto,) * 2)
         t1 = {{'w': jax.device_put(t['w'], NamedSharding(m1, P('data', 'model')))}}
         store = CheckpointStore({str(tmp_path)!r})
         store.save(1, t1)
 
-        m2 = jax.make_mesh((4, 2), ('data', 'model'))
+        m2 = jax.make_mesh((4, 2), ('data', 'model'),
+                           axis_types=(jax.sharding.AxisType.Auto,) * 2)
         sh2 = {{'w': NamedSharding(m2, P('model', 'data'))}}
         out = store.restore(t, shardings=sh2)
         np.testing.assert_array_equal(np.asarray(out['w']), np.asarray(t['w']))
@@ -152,7 +150,8 @@ def test_mini_dryrun_mra_mesh():
         cfg = get_config('granite-8b').reduced()
         lm = LM(cfg, opts=AttnOptions(backend='naive'), remat=False)
         plan = default_plan(cfg).with_replication('ffn', 2)
-        mesh = jax.make_mesh((2, 2, 2), ('data', 'replica', 'shard'))
+        mesh = jax.make_mesh((2, 2, 2), ('data', 'replica', 'shard'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rules = merged_rules(plan, mesh)
         assert rules['ff'] == 'shard'          # ffn tile: K=2 -> replicated
         assert rules['qkv'] == ('replica', 'shard')   # attn: K=1 -> full TP
@@ -160,8 +159,7 @@ def test_mini_dryrun_mra_mesh():
         sh = shardings_for(specs, rules, mesh)
         params = abstract_params(specs)
         toks = jax.ShapeDtypeStruct((4, 32), jnp.int32)
-        from repro.compat import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(lambda p, t: lm.forward(p, tokens=t)[0],
                               in_shardings=(sh, None)).lower(params, toks)
             lowered.compile()

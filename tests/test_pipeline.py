@@ -39,7 +39,8 @@ def test_pipeline_matches_sequential_and_grads():
                 return jnp.tanh(x @ w), None
             return jax.lax.scan(body, x, w_group)[0]
 
-        mesh = jax.make_mesh((S_stages,), ("stage",))
+        mesh = jax.make_mesh((S_stages,), ("stage",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         Wst = stack_layer_groups(W, S_stages)
         y_pipe = pipeline_apply(stage_fn, Wst, x, mesh=mesh,
                                 axis="stage", n_micro=M)

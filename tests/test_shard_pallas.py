@@ -268,7 +268,10 @@ def test_module_caches_bounded_over_1k_configs():
 def test_shard_resolve_and_pad_helpers():
     assert shard.resolve_devices(None) == 1
     assert shard.resolve_devices("auto") == shard.device_count()
-    assert shard.resolve_devices(64) <= shard.device_count()
+    n = shard.device_count()
+    assert shard.resolve_devices(n) == n
+    with pytest.raises(ValueError, match="sees only"):
+        shard.resolve_devices(n + 1)
     with pytest.raises(AssertionError):
         shard.resolve_devices(0)
     assert shard.shard_len(5, 4) == 8 and shard.shard_len(8, 4) == 8

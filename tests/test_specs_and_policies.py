@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 import repro.core as C
 from repro.configs import get_config
 from repro.configs.base import LM_SHAPES, ShapeConfig
@@ -37,7 +37,7 @@ def test_cache_shardings_cover_every_leaf():
 def test_batch_shardings_fallback_drops_trailing_axes():
     """global_batch < product(batch axes) must fall back, never replicate
     silently (the multi-pod FSDP regression)."""
-    mesh = abstract_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     batch = {"tokens": jax.ShapeDtypeStruct((4, 8), jnp.int32)}
     sh = SP.batch_shardings(batch, mesh, extra=("model",))
     spec = sh["tokens"].spec
@@ -46,7 +46,7 @@ def test_batch_shardings_fallback_drops_trailing_axes():
 
 
 def test_param_shardings_respect_divisibility():
-    mesh = abstract_mesh((1, 2), ("data", "model"))
+    mesh = AbstractMesh((1, 2), ("data", "model"))
     cfg = get_config("phi3-medium-14b")       # kv = 10 heads
     lm = LM(cfg, opts=AttnOptions(backend="naive"), remat=False)
     sh = SP.param_shardings(lm, mesh)
