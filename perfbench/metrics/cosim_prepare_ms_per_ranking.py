@@ -1,0 +1,19 @@
+"""Host time per ranking from the engine's run up to its tick loop (the
+arrival tensor, constants, control plan, fault compilation, the jitted
+scan's lookup or build): the program's ``cosim_prepare`` spans over the
+window's rankings."""
+from perfbench import spans
+
+UNIT = "ms"
+LAYER = "co-sim driver"
+MOVES = "cosim_design_ticks_per_s"
+SOURCE = "program_span"
+SPAN = "cosim_prepare"
+
+
+def read(ctx):
+    jobs = spans.window(ctx, "closed_loop_score")
+    s = None if jobs is None else spans.seconds(jobs, SPAN)
+    if s is None:
+        return None
+    return 1e3 * s / len(jobs)

@@ -31,6 +31,7 @@ verification.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from bisect import bisect_right
@@ -575,6 +576,25 @@ def _eval_grid(model: SoCPerfModel, workloads, n_tg: int, backend: str,
             "valid": valid}
 
 
+def _span(name: str):
+    """A span of the program's recorder, :func:`repro.sim.observe.profiled`
+    (imported lazily: the core DSE layer stays importable without
+    ``repro.sim``)."""
+    from repro.sim.observe import profiled
+    return profiled(name)
+
+
+def _spanned(name: str):
+    """Decorator recording every call of the function as span ``name``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with _span(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
+
+
 # bounded: one executable per (device count, model constants) combination
 # actually swept in this process — keyed on scalars only, never arrays
 @lru_cache(maxsize=8)
@@ -665,51 +685,54 @@ def _eval_flat_points(model: SoCPerfModel, workloads, n_tg: int,
     """
     from repro import shard as shard_mod
 
-    coords = np.unravel_index(np.arange(lo, hi), shape)
     A = lay.A
     P = hi - lo
-    kA = np.stack([np.asarray(vals["k"])[coords[lay.k(a)]]
-                   for a in range(A)])
-    faA = np.stack([np.asarray(vals["acc"][a])[coords[lay.fa(a)]]
-                    for a in range(A)])
-    posA = np.stack([np.asarray(vals["pos"])[coords[lay.pos(a)]]
-                     for a in range(A)])
-    hopA = np.stack([model.hop_counts(pos_idx=posA[a])
-                     for a in range(A)]).astype(np.float64)
-    f_noc = np.asarray(vals["noc"])[coords[lay.fnoc]]
-    f_tg = np.asarray(vals["tg"])[coords[lay.ftg]]
+    with _span("sweep_decode"):
+        coords = np.unravel_index(np.arange(lo, hi), shape)
+        kA = np.stack([np.asarray(vals["k"])[coords[lay.k(a)]]
+                       for a in range(A)])
+        faA = np.stack([np.asarray(vals["acc"][a])[coords[lay.fa(a)]]
+                        for a in range(A)])
+        posA = np.stack([np.asarray(vals["pos"])[coords[lay.pos(a)]]
+                         for a in range(A)])
+        hopA = np.stack([model.hop_counts(pos_idx=posA[a])
+                         for a in range(A)]).astype(np.float64)
+        f_noc = np.asarray(vals["noc"])[coords[lay.fnoc]]
+        f_tg = np.asarray(vals["tg"])[coords[lay.ftg]]
 
-    area = np.zeros(P, dtype=np.float64)
-    for a in range(A):
-        area += np.asarray(vals["area"])[coords[lay.k(a)]]
-    valid = np.ones(P, dtype=bool)
-    for a in range(A):
-        for b in range(a + 1, A):
-            valid &= posA[a] != posA[b]
-
-    evaluator = _flat_point_evaluator(
-        int(n_devices), A, int(n_tg),
-        tuple((float(wl.base_mbps), float(wl.wire_share))
-              for wl in workloads),
-        float(model.own_demand), float(model.tg_demand),
-        float(model.noc.link_bw), float(model.hop_latency_share),
-        float(model._ref_hops()), float(model.mem_service),
-        float(model.tg_demand_fig4), tech=lay.tech)
+        area = np.zeros(P, dtype=np.float64)
+        for a in range(A):
+            area += np.asarray(vals["area"])[coords[lay.k(a)]]
+        valid = np.ones(P, dtype=bool)
+        for a in range(A):
+            for b in range(a + 1, A):
+                valid &= posA[a] != posA[b]
+        tech = ([np.asarray(vals[n])[coords[lay.tdim]]
+                 for n in ("tech_ps", "tech_v0", "tech_v1")]
+                if lay.tech else [])
 
     def pad(x: np.ndarray) -> np.ndarray:
         return shard_mod.pad_axis(x, n_devices, axis=x.ndim - 1)
 
-    args = [pad(kA), pad(faA), pad(hopA), pad(f_noc), pad(f_tg)]
-    if lay.tech:
-        tc = coords[lay.tdim]
-        args += [pad(np.asarray(vals[n])[tc])
-                 for n in ("tech_ps", "tech_v0", "tech_v1")]
-    thr, energy, mem = evaluator(*args)
-    return {"throughput": np.asarray(thr)[:P].astype(np.float64),
-            "area": area,
-            "energy_per_unit": np.asarray(energy)[:P].astype(np.float64),
-            "mem_traffic": np.asarray(mem)[:P].astype(np.float64),
-            "valid": valid}
+    # the evaluator call (float64 -> float32 conversion, transfer, device
+    # run), the fetch and the casts back
+    with _span("sweep_device_call"):
+        evaluator = _flat_point_evaluator(
+            int(n_devices), A, int(n_tg),
+            tuple((float(wl.base_mbps), float(wl.wire_share))
+                  for wl in workloads),
+            float(model.own_demand), float(model.tg_demand),
+            float(model.noc.link_bw), float(model.hop_latency_share),
+            float(model._ref_hops()), float(model.mem_service),
+            float(model.tg_demand_fig4), tech=lay.tech)
+        thr, energy, mem = evaluator(*[pad(x) for x in
+                                       (kA, faA, hopA, f_noc, f_tg, *tech)])
+        return {"throughput": np.asarray(thr)[:P].astype(np.float64),
+                "area": area,
+                "energy_per_unit": np.asarray(energy)[:P].astype(
+                    np.float64),
+                "mem_traffic": np.asarray(mem)[:P].astype(np.float64),
+                "valid": valid}
 
 
 def _prepare_axes(model, workloads, ks, acc_rates, noc_rates, tg_rates,
@@ -812,6 +835,7 @@ def _merge_front(cand: Dict[str, np.ndarray],
     return {k: v[keep] for k, v in merged.items()}
 
 
+@_spanned("grid_sweep")
 def grid_sweep(model: SoCPerfModel,
                workloads,
                *,
@@ -946,15 +970,6 @@ def grid_sweep(model: SoCPerfModel,
     n_chunks = 0
     peak_bytes = 0
 
-    try:
-        # lazy: the core DSE layer stays importable without repro.sim
-        from repro.sim.observe import profiled as _profiled
-    except ImportError:                              # pragma: no cover
-        import contextlib
-
-        def _profiled(name):
-            return contextlib.nullcontext()
-
     for o0 in range(0, outer_n, o_per_block):
         o1 = min(o0 + o_per_block, outer_n)
         O = o1 - o0
@@ -970,7 +985,7 @@ def grid_sweep(model: SoCPerfModel,
             return v.reshape(bshape)
 
         blk_shape = (O,) + shape[s:]
-        with _profiled("sweep_chunk"):
+        with _span("sweep_chunk"):
             if n_devices:
                 flat = _eval_flat_points(model, workloads, n_tg, lay, vals,
                                          shape, o0 * inner, o1 * inner,
@@ -983,27 +998,29 @@ def grid_sweep(model: SoCPerfModel,
         peak_bytes = max(peak_bytes, sum(v.nbytes for v in flat.values())
                          + flat["throughput"].nbytes)   # + kernel temp
 
-        vpos = np.nonzero(flat["valid"])[0]
-        n_valid += int(vpos.size)
-        if vpos.size == 0:
-            continue
-        rows = {"i": o0 * inner + vpos,
-                **{o: flat[o][vpos] for o in objs}}
+        # the block's valid rows folded into the running front and top-k
+        with _span("sweep_front"):
+            vpos = np.nonzero(flat["valid"])[0]
+            n_valid += int(vpos.size)
+            if vpos.size == 0:
+                continue
+            rows = {"i": o0 * inner + vpos,
+                    **{o: flat[o][vpos] for o in objs}}
 
-        pre = _front_prefilter(rows["throughput"], rows["area"],
-                               rows["energy_per_unit"])
-        bf = pre[pareto_front_indices(rows["throughput"][pre],
-                                      rows["area"][pre],
-                                      rows["energy_per_unit"][pre])]
-        front = _merge_front(front, {k: v[bf] for k, v in rows.items()})
-        for o, maximize in _TRACKED_OBJECTIVES:
-            key = -rows[o] if maximize else rows[o]
-            sel = _topk_select(key, rows["i"], topk_track)
-            cat = {k: np.concatenate([topk[o][k], v[sel]])
-                   for k, v in rows.items()}
-            ckey = -cat[o] if maximize else cat[o]
-            keep = _topk_select(ckey, cat["i"], topk_track)
-            topk[o] = {k: v[keep] for k, v in cat.items()}
+            pre = _front_prefilter(rows["throughput"], rows["area"],
+                                   rows["energy_per_unit"])
+            bf = pre[pareto_front_indices(rows["throughput"][pre],
+                                          rows["area"][pre],
+                                          rows["energy_per_unit"][pre])]
+            front = _merge_front(front, {k: v[bf] for k, v in rows.items()})
+            for o, maximize in _TRACKED_OBJECTIVES:
+                key = -rows[o] if maximize else rows[o]
+                sel = _topk_select(key, rows["i"], topk_track)
+                cat = {k: np.concatenate([topk[o][k], v[sel]])
+                       for k, v in rows.items()}
+                ckey = -cat[o] if maximize else cat[o]
+                keep = _topk_select(ckey, cat["i"], topk_track)
+                topk[o] = {k: v[keep] for k, v in cat.items()}
 
     # assemble the tracked-survivor store: pareto ∪ top-k, deduped
     pools = [front] + [topk[o] for o in objs]
@@ -1092,6 +1109,7 @@ def _rank_scores(p99: np.ndarray, ept: np.ndarray,
     return np.lexsort((p99, ept, degenerate))  # energy first, p99 tie-break
 
 
+@_spanned("closed_loop_score")
 def closed_loop_score(result: SweepResult, trace, *,
                       model: SoCPerfModel,
                       indices: Optional[Sequence[int]] = None,
@@ -1210,20 +1228,19 @@ def closed_loop_score(result: SweepResult, trace, *,
 
     if batch:
         from repro.sim import BatchSimEngine, BatchSimPlatform
-        platform = BatchSimPlatform.from_design_points(
-            model, result, indices, req_mb=req_mb, n_tg=result.n_tg,
-            flows=flows)
-        controller = (batch_controller_factory(platform)
-                      if batch_controller_factory is not None else None)
-        engine = BatchSimEngine(platform, config=sim_config or SimConfig(),
-                                controller=controller,
-                                balancer=(balancer_factory(platform)
-                                          if balancer_factory is not None
-                                          else None),
-                                backend=backend,
-                                faults=fault_schedule, slo=slo,
-                                observe=observe, devices=devices,
-                                tech=tech)
+        with _span("cosim_build"):
+            platform = BatchSimPlatform.from_design_points(
+                model, result, indices, req_mb=req_mb, n_tg=result.n_tg,
+                flows=flows)
+            controller = (batch_controller_factory(platform)
+                          if batch_controller_factory is not None else None)
+            engine = BatchSimEngine(
+                platform, config=sim_config or SimConfig(),
+                controller=controller,
+                balancer=(balancer_factory(platform)
+                          if balancer_factory is not None else None),
+                backend=backend, faults=fault_schedule, slo=slo,
+                observe=observe, devices=devices, tech=tech)
         r = engine.run(trace)
         p99 = r.p99_latency_s
         ept = r.energy_per_request_j

@@ -52,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.perfmodel import P_DYN_W, P_STATIC_W, V_BASE, V_SLOPE
 from repro.sim.batch import HIGHEST
+from repro.sim.observe import get_profiler
 
 _N_IN_FIXED = 12   # arr, base, req, w, k, hop, tcr, inc, ftg, iotM,
 #                    rates0, guard0
@@ -387,6 +388,7 @@ def tick_kernel_call(arrivals, consts, scalars, init, *,
         kernel, grid=(nb, T), in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch,
         interpret=interpret)
+    get_profiler().count("tick_loop_builds")
 
     def unpack(outs):
         (adm, served, queue, busy, rtt, rates, guard, dropped, energy,

@@ -34,7 +34,9 @@ observe.py   — run-time monitoring: the per-tile/per-link/per-island
                hardware-counter plane (CounterPlane), schema'd
                control-plane tracing (ControlTrace/TraceEvent), the
                Observer level= knob (off/counters/full) every engine
-               accepts via observe=, and wall-clock phase profiling
+               accepts via observe=, and the span and counter
+               recorder (Profiler: phase totals, a ring of spans on the
+               JAX profiler's clock, counters)
 metrics.py   — MetricsRegistry (counter/gauge/histogram) rendering
                Prometheus text + JSON timeseries from telemetry and the
                counter plane

@@ -51,7 +51,6 @@ CPU devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -69,7 +68,8 @@ from repro.sim.engine import (PKT_BYTES, SimConfig, SimPlatform, StepConsts,
 from repro.sim.faults import (CompiledFaults, FaultSchedule, SLOConfig,
                               compile_faults, respill_stranded)
 from repro.sim.flows import FlowPattern, compile_flows
-from repro.sim.observe import STALL_EPS, CounterPlane, Observer
+from repro.sim.observe import (STALL_EPS, CounterPlane, Observer,
+                               get_profiler, profiled)
 from repro.sim.telemetry import BatchTelemetry, TelemetrySchema
 from repro.sim.traffic import BatchTrace, Trace
 
@@ -442,14 +442,19 @@ class BatchSimEngine:
     # ---------------------------------------------------------------- run
     def run(self, trace) -> BatchSimResult:
         """Replay a shared :class:`Trace` (every design sees the same
-        (T, A) arrivals) or a per-design :class:`BatchTrace` (T, B, A)."""
-        if self.backend == "jax":
-            return self._run_jax(trace)
-        if self.backend == "pallas":
-            return self._run_pallas(trace)
-        return self._run_numpy(trace)
+        (T, A) arrivals) or a per-design :class:`BatchTrace` (T, B, A).
 
-    def _run_numpy(self, trace) -> BatchSimResult:
+        Recorded as spans: ``cosim_prepare`` from here up to the tick
+        loop (each backend closes it there), ``cosim_tick_loop`` (the
+        region ``elapsed_wall_s`` times) and ``cosim_percentiles``."""
+        with profiled("cosim_prepare") as prepare:
+            if self.backend == "jax":
+                return self._run_jax(trace, prepare)
+            if self.backend == "pallas":
+                return self._run_pallas(trace, prepare)
+            return self._run_numpy(trace, prepare)
+
+    def _run_numpy(self, trace, prepare) -> BatchSimResult:
         p, cfg = self.platform, self.config
         B, A, T, dt = p.n_designs, p.n_tiles, trace.ticks, trace.dt
         self._check_trace(trace)
@@ -527,122 +532,124 @@ class BatchSimEngine:
             ob.emit(0, "run_start", subject="batch-numpy", ticks=T, dt=dt,
                     designs=B, level=ob.level)
 
-        wall0 = time.perf_counter()
-        for t_i in range(T):
-            for ev in ev_by_tick.get(t_i, ()):
-                telem.event(t_i, ev["kind"],
-                            **{k: v for k, v in ev.items()
-                               if k not in ("tick", "kind")})
-                if ob is not None:
-                    ob.emit_event_dict(t_i, ev)
-            alive = cf.tile_alive[t_i] if has_tile else None
-            lscale = cf.link_scale[t_i] if has_link else None
-            if has_stuck_rate:
-                row = cf.stuck_rate[t_i]
-                if applied_stuck is None or not np.array_equal(
-                        row, applied_stuck, equal_nan=True):
-                    applied_stuck = row
-                    svc = self._service(rates, rate_override=applied_stuck)
-                    if ocap is not None:
-                        ocap.on_service(t_i, svc)
-
-            respill = stranded_exit = None
-            if has_tile and slo.on_kill != "wait":
-                st.queue, st.retry_q, respill, fdrop = respill_stranded(
-                    st.queue, st.retry_q, alive,
-                    self.balancer if recover else None)
-                st.dropped_fault = st.dropped_fault + fdrop.sum(axis=-1)
-                if recover:
-                    st.retried = st.retried + respill.sum(axis=-1)
-                stranded_exit = respill + fdrop
-
-            arr = arrivals[t_i]
-            if carry is not None:
-                arr = arr + carry
-            retry_arr = None
-            if self.balancer is not None:
-                arr = self.balancer.split(
-                    arr, st.queue, prev_cap,
-                    alive=alive if recover else None)
-                if recover:
-                    retry_arr = self.balancer.split(respill, st.queue,
-                                                    prev_cap, alive=alive)
-                    arr = arr + retry_arr
-            out = tick_step(st, arr, svc, consts, alive=alive,
-                            link_scale=lscale, retry_in=retry_arr)
-            if ocap is not None:
-                ocap.on_tick(t_i, out)
-            if carry is not None:
-                carry = out.forwarded
-            if self.balancer is not None:
-                prev_cap = out.cap_tick
-            admitted_hist[t_i] = out.admitted
-            served_hist[t_i] = out.served
-            if track:
-                qd = qdrop_hist[t_i]
-                if stranded_exit is not None:
-                    qd += stranded_exit
-                if out.slo_drop is not None:
-                    qd += out.slo_drop
-                fh["dropped"][t_i] = st.dropped
-                fh["dropped_slo"][t_i] = st.dropped_slo
-                fh["dropped_fault"][t_i] = st.dropped_fault
-                fh["retried"][t_i] = st.retried
-                fh["queue"][t_i] = st.queue.sum(axis=-1)
-                fh["carry"][t_i] = (carry.sum(axis=-1)
-                                    if carry is not None else 0.0)
-
-            win_busy += st.busy
-            win_served += out.served.sum(axis=-1)
-            win_ticks += 1
-            ctl_busy += st.busy
-            ctl_ticks += 1
-
-            if cfg.telemetry_interval and (t_i + 1) % cfg.telemetry_interval == 0:
-                cap_rps_now = out.cap_tick / dt
-                telem.record(
-                    tick=t_i, f_noc=svc["f_noc"], island_rates=rates,
-                    queue_depth=st.queue, busy=win_busy / win_ticks,
-                    throughput_rps=win_served / (win_ticks * dt),
-                    power_w=out.tile_power + out.noc_power,
-                    link_util_max=out.rho.max(axis=-1, initial=0.0),
-                    link_util_mean=out.rho.mean(axis=-1),
-                    latency_est_s=(st.queue.sum(axis=-1)
-                                   / np.maximum(cap_rps_now.sum(axis=-1),
-                                                1e-9)),
-                    dropped=st.dropped, dropped_slo=st.dropped_slo,
-                    dropped_fault=st.dropped_fault, retried=st.retried)
-                win_busy = np.zeros((B, A))
-                win_served = np.zeros(B)
-                win_ticks = 0
-
-            if (self.controller is not None and cfg.control_interval
-                    and (t_i + 1) % cfg.control_interval == 0):
-                t_wire_now = svc["t_wire"] * out.dyn
-                new_rates = self.controller.step(
-                    tick=t_i,
-                    busy=ctl_busy / max(ctl_ticks, 1),
-                    boundness=t_wire_now / (self._t_comp_ref + t_wire_now),
-                    pkts_in=st.pkts_in, pkts_out=st.pkts_out,
-                    rtt=st.rtt_acc,
-                    queue_ticks=st.queue / np.maximum(out.cap_tick, 1e-12),
-                    dead=cf.island_dead[t_i] if has_tile else None,
-                    stuck=(cf.stuck[t_i]
-                           if cf is not None and cf.has_stuck else None))
-                ctl_busy = np.zeros((B, A))
-                ctl_ticks = 0
-                if new_rates is not None:
-                    rates = new_rates
-                    svc = self._service(rates, rate_override=applied_stuck)
-                    if ocap is not None:
-                        ocap.on_service(t_i + 1, svc)
-                    committed = np.nonzero(
-                        self.controller.last_committed)[0].tolist()
-                    telem.event(t_i, "dfs_commit", designs=committed)
+        prepare.close()
+        with profiled("cosim_tick_loop") as loop:
+            for t_i in range(T):
+                for ev in ev_by_tick.get(t_i, ()):
+                    telem.event(t_i, ev["kind"],
+                                **{k: v for k, v in ev.items()
+                                   if k not in ("tick", "kind")})
                     if ob is not None:
-                        ob.emit(t_i, "dfs_commit", subject="batch",
-                                designs=committed)
-        elapsed = time.perf_counter() - wall0
+                        ob.emit_event_dict(t_i, ev)
+                alive = cf.tile_alive[t_i] if has_tile else None
+                lscale = cf.link_scale[t_i] if has_link else None
+                if has_stuck_rate:
+                    row = cf.stuck_rate[t_i]
+                    if applied_stuck is None or not np.array_equal(
+                            row, applied_stuck, equal_nan=True):
+                        applied_stuck = row
+                        svc = self._service(rates, rate_override=applied_stuck)
+                        if ocap is not None:
+                            ocap.on_service(t_i, svc)
+
+                respill = stranded_exit = None
+                if has_tile and slo.on_kill != "wait":
+                    st.queue, st.retry_q, respill, fdrop = respill_stranded(
+                        st.queue, st.retry_q, alive,
+                        self.balancer if recover else None)
+                    st.dropped_fault = st.dropped_fault + fdrop.sum(axis=-1)
+                    if recover:
+                        st.retried = st.retried + respill.sum(axis=-1)
+                    stranded_exit = respill + fdrop
+
+                arr = arrivals[t_i]
+                if carry is not None:
+                    arr = arr + carry
+                retry_arr = None
+                if self.balancer is not None:
+                    arr = self.balancer.split(
+                        arr, st.queue, prev_cap,
+                        alive=alive if recover else None)
+                    if recover:
+                        retry_arr = self.balancer.split(respill, st.queue,
+                                                        prev_cap, alive=alive)
+                        arr = arr + retry_arr
+                out = tick_step(st, arr, svc, consts, alive=alive,
+                                link_scale=lscale, retry_in=retry_arr)
+                if ocap is not None:
+                    ocap.on_tick(t_i, out)
+                if carry is not None:
+                    carry = out.forwarded
+                if self.balancer is not None:
+                    prev_cap = out.cap_tick
+                admitted_hist[t_i] = out.admitted
+                served_hist[t_i] = out.served
+                if track:
+                    qd = qdrop_hist[t_i]
+                    if stranded_exit is not None:
+                        qd += stranded_exit
+                    if out.slo_drop is not None:
+                        qd += out.slo_drop
+                    fh["dropped"][t_i] = st.dropped
+                    fh["dropped_slo"][t_i] = st.dropped_slo
+                    fh["dropped_fault"][t_i] = st.dropped_fault
+                    fh["retried"][t_i] = st.retried
+                    fh["queue"][t_i] = st.queue.sum(axis=-1)
+                    fh["carry"][t_i] = (carry.sum(axis=-1)
+                                        if carry is not None else 0.0)
+
+                win_busy += st.busy
+                win_served += out.served.sum(axis=-1)
+                win_ticks += 1
+                ctl_busy += st.busy
+                ctl_ticks += 1
+
+                if (cfg.telemetry_interval
+                        and (t_i + 1) % cfg.telemetry_interval == 0):
+                    cap_rps_now = out.cap_tick / dt
+                    telem.record(
+                        tick=t_i, f_noc=svc["f_noc"], island_rates=rates,
+                        queue_depth=st.queue, busy=win_busy / win_ticks,
+                        throughput_rps=win_served / (win_ticks * dt),
+                        power_w=out.tile_power + out.noc_power,
+                        link_util_max=out.rho.max(axis=-1, initial=0.0),
+                        link_util_mean=out.rho.mean(axis=-1),
+                        latency_est_s=(st.queue.sum(axis=-1)
+                                       / np.maximum(cap_rps_now.sum(axis=-1),
+                                                    1e-9)),
+                        dropped=st.dropped, dropped_slo=st.dropped_slo,
+                        dropped_fault=st.dropped_fault, retried=st.retried)
+                    win_busy = np.zeros((B, A))
+                    win_served = np.zeros(B)
+                    win_ticks = 0
+
+                if (self.controller is not None and cfg.control_interval
+                        and (t_i + 1) % cfg.control_interval == 0):
+                    t_wire_now = svc["t_wire"] * out.dyn
+                    new_rates = self.controller.step(
+                        tick=t_i,
+                        busy=ctl_busy / max(ctl_ticks, 1),
+                        boundness=t_wire_now / (self._t_comp_ref + t_wire_now),
+                        pkts_in=st.pkts_in, pkts_out=st.pkts_out,
+                        rtt=st.rtt_acc,
+                        queue_ticks=st.queue / np.maximum(out.cap_tick, 1e-12),
+                        dead=cf.island_dead[t_i] if has_tile else None,
+                        stuck=(cf.stuck[t_i]
+                               if cf is not None and cf.has_stuck else None))
+                    ctl_busy = np.zeros((B, A))
+                    ctl_ticks = 0
+                    if new_rates is not None:
+                        rates = new_rates
+                        svc = self._service(rates, rate_override=applied_stuck)
+                        if ocap is not None:
+                            ocap.on_service(t_i + 1, svc)
+                        committed = np.nonzero(
+                            self.controller.last_committed)[0].tolist()
+                        telem.event(t_i, "dfs_commit", designs=committed)
+                        if ob is not None:
+                            ob.emit(t_i, "dfs_commit", subject="batch",
+                                    designs=committed)
+        elapsed = loop.seconds
         if ocap is not None:
             # lazy: the vectorized reconstruction runs on the first
             # observer.counters read, not inside the engine's wall clock
@@ -679,10 +686,11 @@ class BatchSimEngine:
         B, T, dt = self.platform.n_designs, trace.ticks, trace.dt
         p50 = np.empty(B)
         p99 = np.empty(B)
-        for b in range(B):
-            p50[b], p99[b] = latency_percentiles(
-                admitted_hist[:, b], served_hist[:, b], dt,
-                queue_drops=None if qdrops is None else qdrops[:, b])
+        with profiled("cosim_percentiles"):
+            for b in range(B):
+                p50[b], p99[b] = latency_percentiles(
+                    admitted_hist[:, b], served_hist[:, b], dt,
+                    queue_drops=None if qdrops is None else qdrops[:, b])
         sim_seconds = T * dt
         return BatchSimResult(
             n_designs=B, ticks=T, dt=dt,
@@ -1017,12 +1025,13 @@ class BatchSimEngine:
             self._jax_cache.move_to_end(sig)
             return fn
         fn = build()
+        get_profiler().count("tick_loop_builds")
         self._jax_cache[sig] = fn
         while len(self._jax_cache) > _SCAN_CACHE_MAX:
             self._jax_cache.popitem(last=False)
         return fn
 
-    def _run_jax(self, trace) -> BatchSimResult:
+    def _run_jax(self, trace, prepare) -> BatchSimResult:
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -1412,31 +1421,32 @@ class BatchSimEngine:
 
         fn = self._cached_scan(sig, build)
 
-        wall0 = time.perf_counter()
-        carryF, ys = fn(pd, xs0, init)
-        obs_ys = None
-        if observing:
-            *ys, obs_ys = ys
-        if track:
-            admitted, served, qdropT = ys
-            qdrops = np.asarray(qdropT, dtype=np.float64)[:, :B]
-        else:
-            admitted, served = ys
-            qdrops = None
-        (queueF, busyF, rttF, ratesF, guardF, polF, _ctlb, droppedF,
-         energyF, swapsF, _fwdF, _capF, retryqF, dsloF, dfaultF,
-         retriedF) = carryF
-        polF = tuple(np.asarray(s)[:B] for s in polF)
-        queueF, busyF, rttF, ratesF, guardF = [
-            np.asarray(x)[:B]
-            for x in (queueF, busyF, rttF, ratesF, guardF)]
-        droppedF, energyF, swapsF, retryqF, dsloF, dfaultF, retriedF = [
-            np.asarray(x)[:B]
-            for x in (droppedF, energyF, swapsF, retryqF, dsloF,
-                      dfaultF, retriedF)]
-        admitted = np.asarray(admitted, dtype=np.float64)[:, :B]
-        served = np.asarray(served, dtype=np.float64)[:, :B]
-        elapsed = time.perf_counter() - wall0
+        prepare.close()
+        with profiled("cosim_tick_loop") as loop:
+            carryF, ys = fn(pd, xs0, init)
+            obs_ys = None
+            if observing:
+                *ys, obs_ys = ys
+            if track:
+                admitted, served, qdropT = ys
+                qdrops = np.asarray(qdropT, dtype=np.float64)[:, :B]
+            else:
+                admitted, served = ys
+                qdrops = None
+            (queueF, busyF, rttF, ratesF, guardF, polF, _ctlb, droppedF,
+             energyF, swapsF, _fwdF, _capF, retryqF, dsloF, dfaultF,
+             retriedF) = carryF
+            polF = tuple(np.asarray(s)[:B] for s in polF)
+            queueF, busyF, rttF, ratesF, guardF = [
+                np.asarray(x)[:B]
+                for x in (queueF, busyF, rttF, ratesF, guardF)]
+            droppedF, energyF, swapsF, retryqF, dsloF, dfaultF, retriedF = [
+                np.asarray(x)[:B]
+                for x in (droppedF, energyF, swapsF, retryqF, dsloF,
+                          dfaultF, retriedF)]
+            admitted = np.asarray(admitted, dtype=np.float64)[:, :B]
+            served = np.asarray(served, dtype=np.float64)[:, :B]
+        elapsed = loop.seconds
 
         if obs_ys is not None:
             # lazy reconstruction from the raw per-tick ys on the first
@@ -1616,7 +1626,7 @@ class BatchSimEngine:
                     init=init, control_fn=control, control_consts=cctl)
         return args, plan, swaps_before
 
-    def _run_pallas(self, trace) -> BatchSimResult:
+    def _run_pallas(self, trace, prepare) -> BatchSimResult:
         """The fused-kernel backend: the whole queue-update / contention /
         service / forward / control tick as ONE Pallas kernel
         (:func:`repro.kernels.tick_sim.fused_tick_sim`), T grid steps
@@ -1634,15 +1644,16 @@ class BatchSimEngine:
         p = self.platform
         B, A = p.n_designs, p.n_tiles
         args, plan, swaps_before = self._pallas_args(trace)
-        wall0 = time.perf_counter()
-        out = fused_tick_sim(**args)
-        admitted = np.asarray(out["adm"], dtype=np.float64)
-        served = np.asarray(out["served"], dtype=np.float64)
-        queueF = np.asarray(out["queue"], dtype=np.float64)
-        droppedF = np.asarray(out["dropped"], dtype=np.float64)
-        energyF = np.asarray(out["energy"], dtype=np.float64)
-        swapsF = np.asarray(np.rint(out["swaps"]), dtype=np.int64)
-        elapsed = time.perf_counter() - wall0
+        prepare.close()
+        with profiled("cosim_tick_loop") as loop:
+            out = fused_tick_sim(**args)
+            admitted = np.asarray(out["adm"], dtype=np.float64)
+            served = np.asarray(out["served"], dtype=np.float64)
+            queueF = np.asarray(out["queue"], dtype=np.float64)
+            droppedF = np.asarray(out["dropped"], dtype=np.float64)
+            energyF = np.asarray(out["energy"], dtype=np.float64)
+            swapsF = np.asarray(np.rint(out["swaps"]), dtype=np.int64)
+        elapsed = loop.seconds
 
         self._control_writeback(plan, out["rates"], out["guard"],
                                 swapsF, out["pol"], swaps_before)

@@ -23,9 +23,17 @@ batched NumPy ``batch.py``, and the jitted ``lax.scan`` backend):
 * :class:`Observer` — the engine-facing façade with the ``level=`` knob
   (``"off"`` / ``"counters"`` / ``"full"``) so ``closed_loop_score`` can
   run thousands of designs with counters on and tracing off.
-* :class:`Profiler` / :func:`profiled` — wall-clock phase profiling for
-  sweep chunks, tick loops, and scan compilation, feeding per-phase
-  breakdowns into ``BENCH_*`` rows.
+* :class:`Profiler` / :func:`profiled` — the program's span and counter
+  recorder, always on: per-phase wall-clock totals, a bounded ring of
+  :class:`Span` records (name, start, end, enclosing span) on the clock
+  of the JAX profiler's host events, a
+  ``jax.profiler.TraceAnnotation`` per open span so a traced run shows
+  the span beside the device ops, and a ``gc_full`` span per full
+  garbage collection.  The sweep and co-sim drivers record into it
+  (``grid_sweep``, ``sweep_*``, ``closed_loop_score``, ``cosim_*``, the
+  ``tick_loop_builds`` counter); the benchmark's per-layer readers
+  (``perfbench/metrics``, through ``perfbench/spans.py``) and
+  ``BatchSimResult.elapsed_wall_s`` read it.
 
 Zero-perturbation contract: everything here only *reads* the arrays
 ``tick_step`` already computes.  The sequential engine uses the
@@ -39,12 +47,17 @@ Simulated numerics are bit-for-bit identical with monitoring on or off.
 
 from __future__ import annotations
 
+import gc
+import itertools
 import json
+import sys
+import threading
 import time
 from collections import deque
 from contextlib import ContextDecorator
 from dataclasses import dataclass, field
-from typing import Deque, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+from typing import (Deque, Dict, Iterable, List, Mapping, NamedTuple,
+                    Optional, Sequence, Tuple)
 
 import numpy as np
 
@@ -61,6 +74,8 @@ __all__ = [
     "IncrementalCapture",
     "Observer",
     "Profiler",
+    "Span",
+    "RING_CAPACITY",
     "profiled",
     "get_profiler",
     "reset_profiler",
@@ -692,24 +707,82 @@ class IncrementalCapture:
 
 
 # ---------------------------------------------------------------------------
-# Profiler
+# Profiler: phase totals, a ring of spans, counters
 # ---------------------------------------------------------------------------
+
+#: Spans and counter events the ring keeps.  A whole sweep records some 60
+#: spans and a co-sim ranking under 10, so a 40 s window with its warm-up
+#: fills a few per cent of it.
+RING_CAPACITY = 1 << 14
+
+
+class Span(NamedTuple):
+    """One closed span, or one counter event, of a :class:`Profiler`'s
+    ring.  ``start_ns``/``end_ns`` are ``time.time_ns()`` stamps: the
+    clock of the JAX profiler's host events, so a ring and a device trace
+    of one run line up.  A counter event has ``start_ns == end_ns`` and
+    its increment in ``count``."""
+    seq: int                    # open order, unique within one profiler
+    name: str
+    parent: Optional[int]       # seq of the enclosing span; None at a root
+    start_ns: int
+    end_ns: int
+    count: Optional[int] = None
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
+
+
+def _annotate(name: str):
+    """An open ``jax.profiler.TraceAnnotation`` named ``name`` (it lands in
+    the ``/host:CPU`` plane of a traced run), or None where JAX is not
+    imported: the recorder never imports it."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class Profiler:
-    """Wall-clock phase accumulator: ``with prof.profile("scan_compile"):``
-    around a code region books its elapsed time under that phase name."""
+    """Span and counter recorder: ``with prof.profile("sweep_chunk"):``
+    around a code region books its elapsed time under that phase name
+    (:meth:`summary`) and keeps the span — name, start, end, enclosing
+    span — in a bounded ring (:meth:`spans`); :meth:`count` books a
+    running total and a counter event under the current span.
+
+    :meth:`reset` clears the totals only: the ring is a recent history,
+    whose oldest entries fall off first and are counted in ``dropped``.
+    The enclosing span is tracked per thread."""
 
     def __init__(self) -> None:
         self.phases: Dict[str, List[float]] = {}   # name -> [total_s, count]
+        self.counts: Dict[str, int] = {}
+        self._ring: Deque[Span] = deque(maxlen=RING_CAPACITY)
+        self.dropped = 0
+        self._seq = itertools.count()
+        self._local = threading.local()
+        self._gc_open: Optional[tuple] = None
 
     def record(self, name: str, seconds: float) -> None:
         slot = self.phases.setdefault(name, [0.0, 0])
         slot[0] += float(seconds)
         slot[1] += 1
 
-    def profile(self, name: str) -> "_PhaseTimer":
-        return _PhaseTimer(self, name)
+    def profile(self, name: str) -> "_SpanTimer":
+        return _SpanTimer(self, name)
+
+    def count(self, name: str, n: int = 1) -> None:
+        self.counts[name] = self.counts.get(name, 0) + n
+        now = time.time_ns()
+        self._keep(Span(next(self._seq), name, self._current(), now, now,
+                        n))
+
+    def spans(self) -> List[Span]:
+        """The ring, oldest first (spans in the order they closed)."""
+        return list(self._ring)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         return {name: {"total_s": total, "count": count,
@@ -718,29 +791,99 @@ class Profiler:
 
     def reset(self) -> None:
         self.phases.clear()
+        self.counts.clear()
+
+    # -- internals -------------------------------------------------------
+    def _stack(self) -> List[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _current(self) -> Optional[int]:
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    def _keep(self, span: Span) -> None:
+        if len(self._ring) == RING_CAPACITY:
+            self.dropped += 1
+        self._ring.append(span)
+
+    def _on_gc(self, phase: str, info: Mapping[str, object]) -> None:
+        """``gc.callbacks`` hook: each full (generation-2) collection
+        becomes a ``gc_full`` span under the span it interrupted."""
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._gc_open = (next(self._seq), self._current(),
+                             _annotate("gc_full"), time.time_ns())
+        elif self._gc_open is not None:
+            seq, parent, ann, start = self._gc_open
+            self._gc_open = None
+            end = time.time_ns()
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self.record("gc_full", (end - start) * 1e-9)
+            self._keep(Span(seq, "gc_full", parent, start, end))
 
 
-class _PhaseTimer(ContextDecorator):
+class _SpanTimer(ContextDecorator):
+    """One span of a :class:`Profiler`.  ``seconds`` is its duration once
+    closed; :meth:`close` ends it before the ``with`` block does."""
+
     def __init__(self, profiler: Profiler, name: str):
         self.profiler = profiler
         self.name = name
-        self._t0 = 0.0
+        self.seq = -1
+        self.parent: Optional[int] = None
+        self.start_ns = self.end_ns = 0
+        self._ann = None
 
-    def __enter__(self) -> "_PhaseTimer":
-        self._t0 = time.perf_counter()
+    def _recreate_cm(self) -> "_SpanTimer":
+        # a decorated function opens a span of its own on every call
+        return _SpanTimer(self.profiler, self.name)
+
+    def __enter__(self) -> "_SpanTimer":
+        prof = self.profiler
+        stack = prof._stack()
+        self.parent = stack[-1] if stack else None
+        self.seq = next(prof._seq)
+        stack.append(self.seq)
+        self._ann = _annotate(self.name)
+        self.end_ns = 0
+        self.start_ns = time.time_ns()
         return self
 
+    def close(self) -> None:
+        if self.end_ns:
+            return
+        self.end_ns = time.time_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        prof = self.profiler
+        stack = prof._stack()
+        if self.seq in stack:
+            stack.remove(self.seq)
+        prof.record(self.name, self.seconds)
+        prof._keep(Span(self.seq, self.name, self.parent, self.start_ns,
+                        self.end_ns))
+
     def __exit__(self, *exc) -> bool:
-        self.profiler.record(self.name, time.perf_counter() - self._t0)
+        self.close()
         return False
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
 
 
 _GLOBAL_PROFILER = Profiler()
+gc.callbacks.append(_GLOBAL_PROFILER._on_gc)
 
 
 def get_profiler() -> Profiler:
-    """The process-global phase profiler (what :func:`profiled` books to
-    when no explicit profiler is given)."""
+    """The process-global recorder (what :func:`profiled` books to when
+    no explicit profiler is given)."""
     return _GLOBAL_PROFILER
 
 
@@ -748,14 +891,14 @@ def reset_profiler() -> None:
     _GLOBAL_PROFILER.reset()
 
 
-def profiled(name: str, profiler: Optional[Profiler] = None) -> _PhaseTimer:
-    """Context manager / decorator timing a phase into ``profiler`` (the
+def profiled(name: str, profiler: Optional[Profiler] = None) -> _SpanTimer:
+    """Context manager / decorator recording a span into ``profiler`` (the
     global one by default)::
 
         with observe.profiled("sweep_chunk"):
             evaluate(chunk)
     """
-    return _PhaseTimer(profiler or _GLOBAL_PROFILER, name)
+    return _SpanTimer(profiler or _GLOBAL_PROFILER, name)
 
 
 # ---------------------------------------------------------------------------
@@ -778,27 +921,23 @@ class Observer:
     """
 
     def __init__(self, level: str = "counters", *,
-                 trace_capacity: int = 4096,
-                 profiler: Optional[Profiler] = None):
+                 trace_capacity: int = 4096):
         if level not in LEVELS:
             raise ValueError(f"level must be one of {LEVELS}, got {level!r}")
         self.level = level
         self.trace = ControlTrace(capacity=trace_capacity)
         self._counters: Optional[CounterPlane] = None
         self._counters_thunk = None
-        self.profiler = profiler or get_profiler()
 
     @property
     def counters(self) -> Optional[CounterPlane]:
         """The last run's :class:`CounterPlane` — materialized lazily on
         first read.  The engines hand over a finalize thunk instead of a
         built plane (:meth:`attach_lazy`), so the hot tick loop never
-        pays the vectorized reconstruction; it is booked to the phase
-        profiler here, at read time."""
+        pays the vectorized reconstruction."""
         if self._counters is None and self._counters_thunk is not None:
             thunk, self._counters_thunk = self._counters_thunk, None
-            with self.profiler.profile("counters_finalize"):
-                self._counters = thunk()
+            self._counters = thunk()
         return self._counters
 
     # -- coercion --------------------------------------------------------

@@ -13,9 +13,15 @@ The contracts locked down here:
 * **metrics export** — CounterPlane/trace/telemetry -> Prometheus text ->
   parse round-trips, and counter values match the engine's own histories;
 * **the level knob** — ``off`` engages nothing, ``counters`` skips
-  tracing, ``full`` records both; lazy counter materialization books its
-  cost to the phase profiler, not the engine wall clock.
+  tracing, ``full`` records both; counters materialize lazily, outside
+  the engine wall clock;
+* **the span recorder** — phase totals, span nesting, counter events,
+  the bounded ring, ``gc_full`` spans, and spans on the clock of the JAX
+  profiler's host plane.
 """
+import gc
+import glob
+import time
 from functools import partial
 
 import numpy as np
@@ -29,7 +35,9 @@ from repro.sim import (LEVELS, TRACE_KINDS, BatchControllerHarness,
                        ControlTrace, CounterPlane, FaultSchedule,
                        MetricsRegistry, Observer, Profiler, SimConfig,
                        SimEngine, SimPlatform, SLOConfig, export_metrics,
-                       parse_prometheus_text, poisson_trace, profiled)
+                       get_profiler, parse_prometheus_text, poisson_trace,
+                       profiled)
+from repro.sim.observe import RING_CAPACITY
 
 T = 300
 DT = 1e-3
@@ -305,17 +313,14 @@ def test_observer_reuse_across_runs_resets_trace(plat, trace_):
 
 
 def test_lazy_counters_materialize_on_first_read(plat, trace_):
-    prof = Profiler()
-    ob = Observer("counters", profiler=prof)
+    ob = Observer("counters")
     eng = SimEngine(plat, observe=ob)
     eng.run(trace_)
     assert ob._counters is None and ob._counters_thunk is not None
-    assert "counters_finalize" not in prof.phases
     cp = ob.counters
     assert isinstance(cp, CounterPlane)
-    assert prof.phases["counters_finalize"][1] == 1
+    assert ob._counters_thunk is None   # the thunk ran once and is gone
     assert ob.counters is cp            # second read: cached, not re-built
-    assert prof.phases["counters_finalize"][1] == 1
 
 
 # -------------------------------------------------------------- profiling
@@ -335,6 +340,175 @@ def test_profiler_phases_accumulate():
     assert s["phase_a"]["total_s"] >= 0.0
     prof.reset()
     assert prof.summary() == {}
+
+
+def test_summary_books_a_chunk_phase_as_before():
+    """``summary()`` of a ``sweep_chunk``-style phase keeps its keys and
+    its total and count: the sweep cell reads them after a reset."""
+    prof = Profiler()
+    for _ in range(3):
+        with profiled("sweep_chunk", prof):
+            time.sleep(0.002)
+    s = prof.summary()
+    assert list(s) == ["sweep_chunk"]
+    assert set(s["sweep_chunk"]) == {"total_s", "count", "mean_s"}
+    assert s["sweep_chunk"]["count"] == 3
+    ring = [sp.seconds for sp in prof.spans()]
+    assert s["sweep_chunk"]["total_s"] == pytest.approx(sum(ring))
+    assert s["sweep_chunk"]["mean_s"] == pytest.approx(sum(ring) / 3)
+
+
+def test_spans_nest_with_their_parents():
+    prof = Profiler()
+    with profiled("root", prof):
+        with profiled("child", prof):
+            with profiled("leaf", prof):
+                pass
+        with profiled("sibling", prof):
+            pass
+    with profiled("next_root", prof):
+        pass
+    by = {s.name: s for s in prof.spans()}
+    assert [s.name for s in prof.spans()] == [
+        "leaf", "child", "sibling", "root", "next_root"]   # close order
+    assert by["root"].parent is None and by["next_root"].parent is None
+    assert by["child"].parent == by["root"].seq
+    assert by["sibling"].parent == by["root"].seq
+    assert by["leaf"].parent == by["child"].seq
+    for name in ("child", "sibling", "leaf"):
+        s, p = by[name], next(x for x in prof.spans()
+                              if x.seq == by[name].parent)
+        assert p.start_ns <= s.start_ns <= s.end_ns <= p.end_ns
+
+
+def test_a_closed_span_ends_early_and_once():
+    prof = Profiler()
+    with profiled("outer", prof):
+        with profiled("first", prof) as first:
+            first.close()
+            with profiled("second", prof):
+                pass
+    by = {s.name: s for s in prof.spans()}
+    assert by["second"].parent == by["outer"].seq     # not under "first"
+    assert by["first"].end_ns <= by["second"].start_ns
+    assert prof.summary()["first"]["count"] == 1
+    assert first.seconds == by["first"].seconds
+
+
+def test_a_decorated_function_records_a_span_per_call():
+    prof = Profiler()
+
+    @profiled("step", prof)
+    def step(depth):
+        return step(depth - 1) if depth else 0
+
+    step(2)
+    spans = prof.spans()
+    assert [s.name for s in spans] == ["step"] * 3
+    assert spans[0].parent == spans[1].seq and spans[1].parent == spans[2].seq
+
+
+def test_counter_events_sit_under_the_current_span():
+    prof = Profiler()
+    prof.count("builds")
+    with profiled("ranking", prof):
+        prof.count("builds", 2)
+    ev = [s for s in prof.spans() if s.name == "builds"]
+    ranking = next(s for s in prof.spans() if s.name == "ranking")
+    assert [e.count for e in ev] == [1, 2]
+    assert ev[0].parent is None and ev[1].parent == ranking.seq
+    assert ev[1].start_ns == ev[1].end_ns
+    assert ranking.start_ns <= ev[1].start_ns <= ranking.end_ns
+    assert ranking.count is None
+    assert prof.counts == {"builds": 3}
+
+
+def test_ring_is_bounded_and_counts_what_it_dropped():
+    prof = Profiler()
+    extra = 10
+    for i in range(RING_CAPACITY + extra):
+        prof.count("tick", i)
+    ring = prof.spans()
+    assert len(ring) == RING_CAPACITY
+    assert prof.dropped == extra
+    assert ring[0].count == extra and ring[-1].count == RING_CAPACITY + extra - 1
+
+
+def test_reset_clears_totals_but_not_the_ring():
+    prof = Profiler()
+    with profiled("phase", prof):
+        prof.count("c")
+    prof.reset()
+    assert prof.summary() == {} and prof.counts == {}
+    assert [s.name for s in prof.spans()] == ["c", "phase"]
+
+
+def test_a_full_collection_is_recorded_as_gc_full():
+    prof = get_profiler()
+    with profiled("holder"):
+        gc.collect()
+        gc.collect(0)                   # a young generation: not recorded
+    spans = prof.spans()
+    holder = next(s for s in reversed(spans) if s.name == "holder")
+    gcs = [s for s in spans if s.name == "gc_full"
+           and holder.start_ns <= s.start_ns and s.end_ns <= holder.end_ns]
+    assert len(gcs) == 1
+    assert gcs[0].parent == holder.seq
+
+
+def test_spans_land_in_the_host_plane_on_the_ring_clock(tmp_path):
+    """Each span is a ``TraceAnnotation`` of its name in a traced run's
+    ``/host:CPU`` plane, starting within 1 ms of the ring's stamp."""
+    import jax
+    import jax.numpy as jnp
+    prof = Profiler()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with profiled("pb_outer", prof):
+            with profiled("pb_inner", prof):
+                jnp.ones(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path[0])
+    env = dict(data.find_plane_with_name("Task Environment").stats)
+    t0 = env["profile_start_time"]
+    host = data.find_plane_with_name("/host:CPU")
+    seen = {ev.name: t0 + ev.start_ns for line in host.lines
+            for ev in line.events if ev.name.startswith("pb_")}
+    for s in prof.spans():
+        assert s.name in seen, (s.name, sorted(seen))
+        assert abs(seen[s.name] - s.start_ns) < 1e6
+
+
+def test_jitted_names_the_device_trace_readers_look_up(plat):
+    """The sweep evaluator lowers as module ``jit_fn`` and the cached
+    scan as ``jit_run_scan``: ``sweep_eval_roofline`` and
+    ``tick_scan_roofline`` find them in the device trace by those
+    names."""
+    import jax.numpy as jnp
+    from repro.core.dse import _flat_point_evaluator
+    ev = _flat_point_evaluator(1, 1, 0, ((1.0, 0.5),), 1.0, 1.0, 1.0, 0.1,
+                               2.0, 1.0, 1.0)
+    a2, a1 = jnp.ones((1, 4)), jnp.ones(4)
+    assert ev.lower(a2, a2, a2, a1, a1).as_text().startswith(
+        "module @jit_fn")
+
+    bplat = BatchSimPlatform.stack([plat, plat])
+    eng = BatchSimEngine(bplat, backend="jax")
+    seen = []
+    orig = eng._cached_scan
+
+    def spy(sig, build):
+        fn = orig(sig, build)
+
+        def call(*args):
+            seen.append(fn.lower(*args).as_text())
+            return fn(*args)
+        return call
+    eng._cached_scan = spy
+    eng.run(poisson_trace(4000.0, 20, plat.n_tiles, dt=DT, seed=1))
+    assert seen and seen[0].startswith("module @jit_run_scan")
 
 
 # -------------------------------------------------------- counter scoping
