@@ -9,8 +9,8 @@ own ``XLA_FLAGS`` — the same pattern ``tests/test_distributed.py`` uses.
 Two numbers per arm:
 
 * ``eval_seconds`` — steady-state wall time of the device-side flat-point
-  evaluator (``repro.core.dse._flat_point_evaluator``) on a fixed synthetic
-  point batch.  This is the computation ``shard_map`` actually partitions,
+  evaluator (``repro.core.dse._flat_point_evaluator``) on the first
+  ``EVAL_POINTS`` points of a fixed space, which it decodes itself.  This is the computation ``shard_map`` actually partitions,
   so it is what the **>= 2x at 4 virtual devices** acceptance gate runs on.
 * ``sweep_seconds`` — an end-to-end chunked ``grid_sweep(devices=N)``,
   which also pays the serial host-side gather/Pareto-merge work and is
@@ -45,7 +45,8 @@ _ARM = """
 import json, time
 import numpy as np
 import jax
-from repro.core.dse import _flat_point_evaluator, grid_sweep
+from repro.core.dse import (_device_tables, _flat_point_evaluator,
+                            _model_scalars, _prepare_axes, grid_sweep)
 from repro.core.perfmodel import AccelWorkload, SoCPerfModel
 
 n_dev = {n_dev}
@@ -55,28 +56,23 @@ wls = (AccelWorkload("dfadd", 9.22, 0.9),
        AccelWorkload("dfmul", 8.70, 1.1),
        AccelWorkload("dfsin", 0.33, 60.0))
 
-# --- device-side evaluator, fixed synthetic point batch ---
-P, A = {points}, 3
-rng = np.random.default_rng(0)
-kA = rng.choice([1.0, 2.0, 4.0], size=(A, P))
-faA = rng.uniform(0.2, 1.0, size=(A, P))
-hopA = rng.integers(1, 6, size=(A, P)).astype(np.float64)
-fn = rng.uniform(0.3, 1.0, size=P)
-ft = rng.uniform(0.3, 1.0, size=P)
-ev = _flat_point_evaluator(
-    n_dev, A, 2,
-    tuple((float(w.base_mbps), float(w.wire_share)) for w in wls),
-    float(model.own_demand), float(model.tg_demand),
-    float(model.noc.link_bw), float(model.hop_latency_share),
-    float(model._ref_hops()), float(model.mem_service),
-    float(model.tg_demand_fig4))
-out = ev(kA, faA, hopA, fn, ft)          # compile + warm
+# --- device-side evaluator, the first P points of a fixed space ---
+P = {points}
+lay, axes, vals = _prepare_axes(
+    model, wls, (1, 2, 4), (0.2, 0.4, 0.6, 0.8, 1.0),
+    (0.25, 0.5, 0.75, 1.0), (0.5, 1.0), ((1, 1), (3, 3), (0, 2), (2, 2)),
+    "independent")
+sizes = np.asarray([len(v) for _, v in axes], dtype=np.int32)
+args = (P, np.zeros_like(sizes), sizes, _device_tables(model, lay, vals))
+ev = _flat_point_evaluator(n_dev, *_model_scalars(model, wls, 2),
+                           independent=True)
+out = ev(*args)          # compile + warm
 for o in out:
     o.block_until_ready()
 best = float("inf")
 for _ in range({reps}):
     t0 = time.perf_counter()
-    out = ev(kA, faA, hopA, fn, ft)
+    out = ev(*args)
     for o in out:
         o.block_until_ready()
     best = min(best, time.perf_counter() - t0)

@@ -595,144 +595,271 @@ def _spanned(name: str):
     return wrap
 
 
-# bounded: one executable per (device count, model constants) combination
-# actually swept in this process — keyed on scalars only, never arrays
+# value tables are rows of this many entries (or of the next power of two
+# that holds the longest): every space whose axes have at most this many
+# values runs the same executable
+_TABLE_WIDTH = 32
+
+
+def _device_tables(model: SoCPerfModel, lay: _AxisLayout,
+                   vals: Dict[str, object]) -> np.ndarray:
+    """The value tables the flat-point evaluator looks axis values up in:
+    one float32 row each, zero-padded to :data:`_TABLE_WIDTH` entries (or
+    the next power of two that holds the longest).  Rows, in order: the
+    K ladder, the NoC ladder, one rate ladder per rate axis (``lay.R``),
+    the TG ladder, the hop count of each candidate position, then the
+    tech axis's ``p_scale``, ``v0`` and ``v1``.  Each is cast from the
+    float64 the host path holds, so every value is the float32 it was
+    on the host."""
+    rows = [vals["k"], vals["noc"], *vals["acc"][:lay.R], vals["tg"],
+            model.hop_counts(pos_idx=vals["pos"])]
+    if lay.tech:
+        rows += [vals["tech_ps"], vals["tech_v0"], vals["tech_v1"]]
+    longest = max(len(r) for r in rows)
+    tables = np.zeros((len(rows), max(_TABLE_WIDTH,
+                                      1 << (longest - 1).bit_length())),
+                      dtype=np.float32)
+    for t, r in enumerate(rows):
+        tables[t, :len(r)] = np.asarray(r, dtype=np.float64)
+    return tables
+
+
+def _divmod(q, n):
+    """``(q // n, q % n)`` of int32 jax arrays, ``0 <= q < 2**30`` and
+    ``n >= 1``, from float32 quotients: an estimate and a second pass on
+    its remainder leave the quotient at most one off, and a compare puts
+    it right.  (For a TPU v5e, one int32 division by a traced divisor
+    takes the compiler about a minute.)"""
+    import jax.numpy as jnp
+    i32, f32 = jnp.int32, jnp.float32
+    nf = n.astype(f32)
+    d = jnp.floor(q.astype(f32) / nf).astype(i32)
+    d = d + jnp.floor((q - d * n).astype(f32) / nf).astype(i32)
+    r = q - d * n
+    fix = (r >= n).astype(i32) - (r < 0).astype(i32)
+    return d + fix, r - fix * n
+
+
+def _decode_digits(i, start, sizes):
+    """Axis coordinates of the flat points ``start + i`` (C order), in
+    int32 jax math: ``i`` (P,) holds offsets into a chunk, below 2**30,
+    ``start`` (ndim,) the coordinates of the chunk's first point and
+    ``sizes`` (ndim,) the axis sizes.  The digits of ``i`` are added to
+    ``start``'s with carries, last axis first, so no int32 ever holds a
+    global flat index (spaces can exceed 2**31 points).  Returns ndim
+    (P,) arrays; lanes past the space's last point wrap around."""
+    import jax.numpy as jnp
+    digits = [None] * start.shape[0]
+    q, carry = i, jnp.zeros_like(i)
+    for d in reversed(range(start.shape[0])):
+        n = jnp.broadcast_to(sizes[d], i.shape)
+        q, r = _divmod(q, n)
+        s = r + start[d] + carry
+        carry = (s >= n).astype(i.dtype)
+        digits[d] = s - carry * n
+    return digits
+
+
+def _objectives(A: int, n_tg: int, base_wire, own_demand: float,
+                tg_demand: float, link_bw: float, hop_latency_share: float,
+                ref_hops: float, mem_service: float, tg_demand_fig4: float,
+                kA, faA, hopA, f_noc, f_tg, tech=None):
+    """(throughput, energy per unit, memory traffic) of flat points from
+    their axis values, in jax: ``kA``, ``faA``, ``hopA`` hold one (P,)
+    row per accelerator, ``tech`` the (p_scale, v0, v1) arrays of the
+    physical power model or None for the linear voltage proxy.
+
+    The math is the same fixed-order accel loop as :func:`_eval_grid`
+    (``_throughput_math`` / ``chip_power`` / the per-accel Fig.-4 memory
+    model)."""
+    import jax.numpy as jnp
+
+    thr = jnp.zeros_like(f_noc)
+    for a, (base, wire) in enumerate(base_wire):
+        thr = thr + _throughput_math(
+            jnp, base, wire, kA[a], faA[a], f_noc, f_tg, n_tg, hopA[a],
+            own_demand=own_demand, tg_demand=tg_demand, link_bw=link_bw,
+            hop_latency_share=hop_latency_share, ref_hops=ref_hops)
+    mem = _memory_traffic_math_per_accel(
+        jnp, [faA[a] for a in range(A)], f_noc, f_tg, n_tg,
+        mem_service=mem_service, tg_demand_fig4=tg_demand_fig4)
+    if tech is not None:
+        ps, v0, v1 = tech
+        pw = chip_power_coeffs(faA[0], 1.0, v0, v1, ps)
+        for a in range(1, A):
+            pw = pw + chip_power_coeffs(faA[a], 1.0, v0, v1, ps)
+        power = pw / float(A) \
+            + NOC_POWER_SHARE * chip_power_coeffs(f_noc, 1.0, v0, v1, ps)
+    else:
+        pw = chip_power(faA[0], busy=1.0)
+        for a in range(1, A):
+            pw = pw + chip_power(faA[a], busy=1.0)
+        power = pw / float(A) + NOC_POWER_SHARE * chip_power(f_noc,
+                                                            busy=1.0)
+    energy = power / jnp.maximum(thr, 1e-9)
+    return thr, energy, mem
+
+
+def _model_scalars(model: SoCPerfModel, workloads, n_tg: int) -> tuple:
+    """The scalars :func:`_objectives` takes before its arrays (and the
+    flat-point evaluator's cache key after the device count)."""
+    return (len(workloads), int(n_tg),
+            tuple((float(wl.base_mbps), float(wl.wire_share))
+                  for wl in workloads),
+            float(model.own_demand), float(model.tg_demand),
+            float(model.noc.link_bw), float(model.hop_latency_share),
+            float(model._ref_hops()), float(model.mem_service),
+            float(model.tg_demand_fig4))
+
+
+# bounded: one executable per (device count, model constants, layout)
+# combination actually swept in this process — keyed on scalars only,
+# never arrays; jit adds one per static point count
 @lru_cache(maxsize=8)
 def _flat_point_evaluator(n_devices: int, A: int, n_tg: int,
                           base_wire: Tuple[Tuple[float, float], ...],
                           own_demand: float, tg_demand: float,
                           link_bw: float, hop_latency_share: float,
                           ref_hops: float, mem_service: float,
-                          tg_demand_fig4: float, tech: bool = False):
+                          tg_demand_fig4: float, tech: bool = False,
+                          independent: bool = False):
     """jit-compiled (and, for ``n_devices > 1``, ``shard_map``-sharded)
-    evaluator of the three float objectives over a flat (P,) point axis.
+    evaluator of the three float objectives of P consecutive flat points
+    of a sweep, which it decodes itself.
 
-    The math is the same fixed-order accel loop as :func:`_eval_grid`
-    (``_throughput_math`` / ``chip_power`` / the per-accel Fig.-4 memory
-    model), expressed in jax so the point axis can be partitioned across
-    devices.  Sharding only splits an elementwise computation, so every
-    device count produces identical floats — tested 1-vs-N in
-    ``tests/test_shard_pallas.py``.  Runs at jax default precision (f32),
+    Called as ``fn(P, start, sizes, tables)``: ``P`` the (static) point
+    count, a multiple of ``n_devices``; ``start`` the int32 coordinates
+    of the first point; ``sizes`` the int32 axis sizes of the layout
+    ``_AxisLayout(A, independent, tech)``; ``tables`` from
+    :func:`_device_tables`.  Each point's coordinates come from an iota
+    by :func:`_decode_digits`, its axis values from its table row by a
+    chain of selects (exact; on a TPU v5e some 80 times faster than a
+    gather from the same row), and :func:`_objectives` does the math.  Returns ``(thr,
+    energy, mem)``, each (P,) float32.  The executable depends on P, the
+    layout and the tables' width, never on how many values an axis has,
+    so a cut space compiles what the whole space runs.
+
+    Sharding only splits an elementwise computation (each shard adds its
+    own offset), so every device count produces identical floats —
+    tested 1-vs-N in ``tests/test_shard_pallas.py``.  Runs in float32,
     so results deviate ~1e-6 relative from the numpy f64 path, which
     stays the ground truth for ``devices=None``.
-
-    ``tech=True`` compiles the physical-DVFS variant: three extra (P,)
-    inputs ``(p_scale, v0, v1)`` — one tech coefficient triple per point —
-    replace the linear voltage proxy in the power term.
     """
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
     from repro import shard as shard_mod
     from jax.sharding import PartitionSpec
 
-    def _thr_mem(kA, faA, f_noc, f_tg, hopA):
-        thr = jnp.zeros_like(f_noc)
-        for a, (base, wire) in enumerate(base_wire):
-            thr = thr + _throughput_math(
-                jnp, base, wire, kA[a], faA[a], f_noc, f_tg, n_tg, hopA[a],
-                own_demand=own_demand, tg_demand=tg_demand, link_bw=link_bw,
-                hop_latency_share=hop_latency_share, ref_hops=ref_hops)
-        mem = _memory_traffic_math_per_accel(
-            jnp, [faA[a] for a in range(A)], f_noc, f_tg, n_tg,
-            mem_service=mem_service, tg_demand_fig4=tg_demand_fig4)
-        return thr, mem
+    lay = _AxisLayout(A=A, independent=independent, tech=tech)
+    R = lay.R
+    scalars = (A, n_tg, base_wire, own_demand, tg_demand, link_bw,
+               hop_latency_share, ref_hops, mem_service, tg_demand_fig4)
 
-    if tech:
-        def fn(kA, faA, hopA, f_noc, f_tg, ps, v0, v1):
-            thr, mem = _thr_mem(kA, faA, f_noc, f_tg, hopA)
-            pw = chip_power_coeffs(faA[0], 1.0, v0, v1, ps)
-            for a in range(1, A):
-                pw = pw + chip_power_coeffs(faA[a], 1.0, v0, v1, ps)
-            power = pw / float(A) \
-                + NOC_POWER_SHARE * chip_power_coeffs(f_noc, 1.0, v0, v1, ps)
-            energy = power / jnp.maximum(thr, 1e-9)
-            return thr, energy, mem
-        n_in = 8
-    else:
-        def fn(kA, faA, hopA, f_noc, f_tg):
-            thr, mem = _thr_mem(kA, faA, f_noc, f_tg, hopA)
-            pw = chip_power(faA[0], busy=1.0)
-            for a in range(1, A):
-                pw = pw + chip_power(faA[a], busy=1.0)
-            power = pw / float(A) + NOC_POWER_SHARE * chip_power(f_noc,
-                                                                busy=1.0)
-            energy = power / jnp.maximum(thr, 1e-9)
-            return thr, energy, mem
-        n_in = 5
+    def points(n, first, start, sizes, tables):
+        def lookup(_):
+            d = _decode_digits(lax.iota(jnp.int32, n) + first, start, sizes)
+
+            def value(table, dim):
+                v = jnp.broadcast_to(tables[table, 0], (n,))
+                for j in range(1, tables.shape[1]):
+                    v = jnp.where(d[dim] == j, tables[table, j], v)
+                return v
+            return (jnp.stack([value(0, lay.k(a)) for a in range(A)]),
+                    jnp.stack([value(2 + (a if independent else 0),
+                                     lay.fa(a)) for a in range(A)]),
+                    jnp.stack([value(3 + R, lay.pos(a)) for a in range(A)]),
+                    value(1, lay.fnoc), value(2 + R, lay.ftg),
+                    *(value(4 + R + j, lay.tdim)
+                      for j in range(3 if tech else 0)))
+
+        def zeros(_):
+            return tuple(jnp.zeros(s, jnp.float32) for s in
+                         [(A, n)] * 3 + [(n,)] * (5 if tech else 2))
+
+        # the axis sizes are positive, so the decode always runs; XLA
+        # fuses nothing across a conditional, so the math below compiles
+        # as it did on these arrays uploaded from the host, down to where
+        # a backend contracts a product into a sum: the same floats
+        kA, faA, hopA, f_noc, f_tg, *coeffs = lax.cond(
+            sizes[0] > 0, lookup, zeros, None)
+        return _objectives(*scalars, kA, faA, hopA, f_noc, f_tg,
+                           coeffs or None)
 
     if n_devices <= 1:
-        return jax.jit(fn)
+        def fn(P, start, sizes, tables):
+            return points(P, 0, start, sizes, tables)
+        return jax.jit(fn, static_argnums=0)
+
     mesh = shard_mod.device_mesh(n_devices, "points")
-    s2 = PartitionSpec(None, "points")
-    s1 = PartitionSpec("points")
-    return jax.jit(jax.shard_map(
-        fn, mesh=mesh, in_specs=(s2, s2, s2) + (s1,) * (n_in - 3),
-        out_specs=(s1, s1, s1), check_vma=False))
+    rep, s1 = PartitionSpec(), PartitionSpec("points")
+
+    def fn(P, start, sizes, tables):
+        n = P // n_devices
+
+        def shard(*args):
+            return points(n, lax.axis_index("points") * n, *args)
+        return jax.shard_map(shard, mesh=mesh, in_specs=(rep,) * 3,
+                             out_specs=(s1,) * 3, check_vma=False)(
+            start, sizes, tables)
+    return jax.jit(fn, static_argnums=0)
 
 
 def _eval_flat_points(model: SoCPerfModel, workloads, n_tg: int,
                       lay: _AxisLayout, vals: Dict[str, object],
-                      shape: Tuple[int, ...], lo: int, hi: int,
+                      shape: Tuple[int, ...], lo: int, hi: int, get,
+                      blk_shape: Tuple[int, ...],
                       n_devices: int) -> Dict[str, np.ndarray]:
-    """Evaluate global flat points ``[lo, hi)`` as flat (P,) arrays.
+    """Evaluate global flat points ``[lo, hi)``, the block ``blk_shape``
+    whose axis arrays ``get(dim, values)`` gives, as flat (P,) arrays.
 
-    The per-point axis gathers, the area sum and the placement-validity
-    mask stay host-side (cheap integer work, bit-identical regardless of
-    device count); the float objective math runs through the sharded
-    :func:`_flat_point_evaluator`.  The point axis is padded to a device
-    multiple (padded lanes replicate point 0 and are sliced off).
+    The host decodes only ``lo`` into coordinates and broadcasts the
+    area sum and the placement-validity mask (float64 and bool, as
+    :func:`_eval_grid` does); :func:`_flat_point_evaluator` decodes every
+    point on the device and returns the float objectives in float32,
+    which callers cast to float64 (the chunked sweep after selecting the
+    valid rows).  The point axis is padded to a device multiple (padded
+    lanes are sliced off).
     """
     from repro import shard as shard_mod
+    from repro.sim.observe import get_profiler
 
     A = lay.A
     P = hi - lo
+    n = shard_mod.shard_len(P, n_devices)
+    if n >= 2 ** 30:
+        raise ValueError(f"{P} points in one evaluator call; pass a "
+                         "chunk_points below 2**30")
     with _span("sweep_decode"):
-        coords = np.unravel_index(np.arange(lo, hi), shape)
-        kA = np.stack([np.asarray(vals["k"])[coords[lay.k(a)]]
-                       for a in range(A)])
-        faA = np.stack([np.asarray(vals["acc"][a])[coords[lay.fa(a)]]
-                        for a in range(A)])
-        posA = np.stack([np.asarray(vals["pos"])[coords[lay.pos(a)]]
-                         for a in range(A)])
-        hopA = np.stack([model.hop_counts(pos_idx=posA[a])
-                         for a in range(A)]).astype(np.float64)
-        f_noc = np.asarray(vals["noc"])[coords[lay.fnoc]]
-        f_tg = np.asarray(vals["tg"])[coords[lay.ftg]]
-
-        area = np.zeros(P, dtype=np.float64)
+        ones = (1,) * len(blk_shape)
+        area = np.zeros(ones, dtype=np.float64)
         for a in range(A):
-            area += np.asarray(vals["area"])[coords[lay.k(a)]]
-        valid = np.ones(P, dtype=bool)
+            area = area + get(lay.k(a), vals["area"])
+        pos_ax = [get(lay.pos(a), vals["pos"]) for a in range(A)]
+        valid = np.ones(ones, dtype=bool)
         for a in range(A):
             for b in range(a + 1, A):
-                valid &= posA[a] != posA[b]
-        tech = ([np.asarray(vals[n])[coords[lay.tdim]]
-                 for n in ("tech_ps", "tech_v0", "tech_v1")]
-                if lay.tech else [])
+                valid = valid & (pos_ax[a] != pos_ax[b])
+        area = np.broadcast_to(area, blk_shape).ravel()
+        valid = np.broadcast_to(valid, blk_shape).ravel()
+        start = np.asarray(np.unravel_index(lo, shape), dtype=np.int32)
+        tables = _device_tables(model, lay, vals)
 
-    def pad(x: np.ndarray) -> np.ndarray:
-        return shard_mod.pad_axis(x, n_devices, axis=x.ndim - 1)
-
-    # the evaluator call (float64 -> float32 conversion, transfer, device
-    # run), the fetch and the casts back
+    # the evaluator call (transfer of the start and the tables, device
+    # decode and math) and the fetch
     with _span("sweep_device_call"):
         evaluator = _flat_point_evaluator(
-            int(n_devices), A, int(n_tg),
-            tuple((float(wl.base_mbps), float(wl.wire_share))
-                  for wl in workloads),
-            float(model.own_demand), float(model.tg_demand),
-            float(model.noc.link_bw), float(model.hop_latency_share),
-            float(model._ref_hops()), float(model.mem_service),
-            float(model.tg_demand_fig4), tech=lay.tech)
-        thr, energy, mem = evaluator(*[pad(x) for x in
-                                       (kA, faA, hopA, f_noc, f_tg, *tech)])
-        return {"throughput": np.asarray(thr)[:P].astype(np.float64),
-                "area": area,
-                "energy_per_unit": np.asarray(energy)[:P].astype(
-                    np.float64),
-                "mem_traffic": np.asarray(mem)[:P].astype(np.float64),
-                "valid": valid}
+            int(n_devices), *_model_scalars(model, workloads, n_tg),
+            tech=lay.tech, independent=lay.independent)
+        thr, energy, mem = evaluator(n, start,
+                                     np.asarray(shape, dtype=np.int32),
+                                     tables)
+        out = {"throughput": np.asarray(thr)[:P], "area": area,
+               "energy_per_unit": np.asarray(energy)[:P],
+               "mem_traffic": np.asarray(mem)[:P], "valid": valid}
+    get_profiler().count("sweep_points_decoded_on_device", P)
+    return out
 
 
 def _prepare_axes(model, workloads, ks, acc_rates, noc_rates, tg_rates,
@@ -893,8 +1020,10 @@ def grid_sweep(model: SoCPerfModel,
 
     **Multi-device sharding**: ``devices=`` (``None`` / int / ``"auto"``,
     see :func:`repro.shard.resolve_devices`) switches each block (or the
-    whole grid on the dense path) to a flat per-point jax evaluator whose
-    point axis is ``shard_map``-partitioned across devices.  Any device
+    whole grid on the dense path) to a flat per-point jax evaluator that
+    decodes the block's points itself, from the block's first coordinates
+    and the axes' value tables, and whose point axis is
+    ``shard_map``-partitioned across devices.  Any device
     count — including 1 — produces identical floats (sharding only splits
     elementwise math); ``devices=None`` keeps the numpy float64 path as
     the bit-for-bit ground truth, against which the jax float32 path
@@ -932,11 +1061,14 @@ def grid_sweep(model: SoCPerfModel,
 
     t0 = time.perf_counter()
     if chunk_points is None or n_points <= chunk_points:
+        get = lambda dim, v: _axis(v, dim, ndim)    # noqa: E731
         if n_devices:
             out = _eval_flat_points(model, workloads, n_tg, lay, vals,
-                                    shape, 0, n_points, n_devices)
+                                    shape, 0, n_points, get, shape,
+                                    n_devices)
+            for o in ("throughput", "energy_per_unit", "mem_traffic"):
+                out[o] = out[o].astype(np.float64)
         else:
-            get = lambda dim, v: _axis(v, dim, ndim)    # noqa: E731
             out = _eval_grid(model, workloads, n_tg, backend, lay, vals,
                              get, shape)
         elapsed = time.perf_counter() - t0
@@ -989,7 +1121,7 @@ def grid_sweep(model: SoCPerfModel,
             if n_devices:
                 flat = _eval_flat_points(model, workloads, n_tg, lay, vals,
                                          shape, o0 * inner, o1 * inner,
-                                         n_devices)
+                                         get, blk_shape, n_devices)
             else:
                 out = _eval_grid(model, workloads, n_tg, backend, lay,
                                  vals, get, blk_shape)
@@ -1005,7 +1137,8 @@ def grid_sweep(model: SoCPerfModel,
             if vpos.size == 0:
                 continue
             rows = {"i": o0 * inner + vpos,
-                    **{o: flat[o][vpos] for o in objs}}
+                    **{o: flat[o][vpos].astype(np.float64, copy=False)
+                       for o in objs}}
 
             pre = _front_prefilter(rows["throughput"], rows["area"],
                                    rows["energy_per_unit"])

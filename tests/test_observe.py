@@ -486,13 +486,15 @@ def test_jitted_names_the_device_trace_readers_look_up(plat):
     scan as ``jit_run_scan``: ``sweep_eval_roofline`` and
     ``tick_scan_roofline`` find them in the device trace by those
     names."""
-    import jax.numpy as jnp
     from repro.core.dse import _flat_point_evaluator
     ev = _flat_point_evaluator(1, 1, 0, ((1.0, 0.5),), 1.0, 1.0, 1.0, 0.1,
                                2.0, 1.0, 1.0)
-    a2, a1 = jnp.ones((1, 4)), jnp.ones(4)
-    assert ev.lower(a2, a2, a2, a1, a1).as_text().startswith(
-        "module @jit_fn")
+    # one accelerator: K, f_noc, f_acc, f_tg and position axes, 2 values
+    # each, and their five value tables
+    axes = np.full(5, 2, dtype=np.int32)
+    tables = np.ones((5, 32), dtype=np.float32)
+    assert ev.lower(4, np.zeros_like(axes), axes,
+                    tables).as_text().startswith("module @jit_fn")
 
     bplat = BatchSimPlatform.stack([plat, plat])
     eng = BatchSimEngine(bplat, backend="jax")

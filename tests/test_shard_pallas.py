@@ -251,10 +251,13 @@ def test_module_caches_bounded_over_1k_configs():
     assert info.currsize <= 32, info
 
     # dse: the sharded flat-point evaluator cache is scalar-keyed + bounded
+    # (the layout is two flags; point counts and tables are call data)
     assert dse_mod._flat_point_evaluator.cache_info().maxsize == 8
     for i in range(20):
         dse_mod._flat_point_evaluator(1, 2, i, ((1.0, 0.1), (2.0, 0.01)),
-                                      0.1, 0.07, 1.0, 0.03, 2.0, 8.0, 0.5)
+                                      0.1, 0.07, 1.0, 0.03, 2.0, 8.0, 0.5,
+                                      tech=i % 2 == 1,
+                                      independent=i % 3 == 1)
     info = dse_mod._flat_point_evaluator.cache_info()
     assert info.currsize <= 8, info
 

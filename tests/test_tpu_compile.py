@@ -93,20 +93,23 @@ def test_tick_kernel_compiles_for_v5e(one_chip, variant):
 
 
 def test_flat_point_evaluator_compiles_for_v5e(one_chip):
-    from repro.core.dse import _flat_point_evaluator
+    """The evaluator that decodes its own points, at a chunk's size: it
+    reads a few small tables and writes the three objectives."""
+    from repro.core.dse import (_device_tables, _flat_point_evaluator,
+                                _model_scalars, _prepare_axes)
     from repro.core.perfmodel import SoCPerfModel
     m = SoCPerfModel()
-    ev = _flat_point_evaluator(
-        1, 3, 4, tuple((float(w.base_mbps), float(w.wire_share))
-                       for w in _workloads()),
-        float(m.own_demand), float(m.tg_demand), float(m.noc.link_bw),
-        float(m.hop_latency_share), float(m._ref_hops()),
-        float(m.mem_service), float(m.tg_demand_fig4))
-    f32 = jnp.float32
-    shapes = ([jax.ShapeDtypeStruct((3, FLAT_POINTS), f32,
-                                    sharding=one_chip)] * 3
-              + [jax.ShapeDtypeStruct((FLAT_POINTS,), f32,
-                                      sharding=one_chip)] * 2)
-    compiled = ev.lower(*shapes).compile()
-    out = compiled.memory_analysis().output_size_in_bytes
-    assert out >= 3 * FLAT_POINTS * 4        # thr, energy, mem_traffic
+    wls = _workloads()
+    lay, axes, vals = _prepare_axes(
+        m, wls, (1, 2, 4), (0.2, 0.6, 1.0), (0.5, 1.0), (0.5, 1.0),
+        ((1, 1), (3, 3), (0, 2)), "independent")
+    tables = _device_tables(m, lay, vals)
+    ev = _flat_point_evaluator(1, *_model_scalars(m, wls, 4),
+                               independent=True)
+    i32 = jnp.int32
+    shapes = [jax.ShapeDtypeStruct((len(axes),), i32, sharding=one_chip)] * 2
+    shapes += _shapes([tables], one_chip)
+    compiled = ev.lower(FLAT_POINTS, *shapes).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 3 * FLAT_POINTS * 4   # thr, e, mem
+    assert mem.argument_size_in_bytes < 16 * 1024     # no per-point input
