@@ -230,7 +230,8 @@ def fused_tick_sim(arrivals, consts, scalars, init, *,
     (see :func:`tick_kernel_call` for the arguments), compiled on a TPU
     and interpreted on the CPU.  Returns a dict of f32 outputs
     (``adm``/``served`` histories, final state, accumulators, evolved
-    control state) sliced back to the true ``B``."""
+    control state) sliced back to the true ``B``: NumPy arrays, but for
+    the two histories, which stay on the device."""
     call, inputs, unpack = tick_kernel_call(
         arrivals, consts, scalars, init, control_fn=control_fn,
         control_consts=control_consts, block_b=block_b,
@@ -398,8 +399,8 @@ def tick_kernel_call(arrivals, consts, scalars, init, *,
             else np.asarray(p)[:B].astype(dtp)
             for p, dtp in zip(outs[10:], pol_dtypes))
         return {
-            "adm": np.asarray(adm)[:, :B],
-            "served": np.asarray(served)[:, :B],
+            "adm": adm[:, :B],              # left on the device
+            "served": served[:, :B],
             "queue": np.asarray(queue)[:B],
             "busy": np.asarray(busy)[:B],
             "rtt": np.asarray(rtt)[:B],

@@ -30,6 +30,9 @@ faults.py    — FaultSchedule (tile/island kills, link degradation, stuck
                replay one schedule, bit-for-bit at B=1
 telemetry.py — ring-buffer time series + JSON export (per-design rings
                for the batched engine), incl. drop/retry fault counters
+percentiles.py — the batched engine's p50/p99 on the device (jax and
+               pallas backends), certified equal to the host's float64
+               latency_percentiles, which recomputes what it cannot certify
 observe.py   — run-time monitoring: the per-tile/per-link/per-island
                hardware-counter plane (CounterPlane), schema'd
                control-plane tracing (ControlTrace/TraceEvent), the

@@ -40,8 +40,10 @@ CPU).  The jax backend supports open-loop replay, the
 vectorized membound/PID policies (+ queue guard), *custom* jax-side
 batch policies (any policy exposing the ``jax_step`` protocol — see
 :meth:`BatchSimEngine._control_plan`), flow patterns, per-design traces
-and the balancer; it records no telemetry rings (latency percentiles
-are still reconstructed exactly from the returned histories).  With
+and the balancer; it records no telemetry rings.  On both device
+backends the latency percentiles are reconstructed on the device from the
+histories the tick loop leaves there, certified equal to the host's
+float64 reconstruction (:mod:`repro.sim.percentiles`).  With
 ``devices=`` the jax backend shards the design axis across devices via
 ``jax.shard_map`` (mesh plumbing in ``repro.shard``): the
 per-design rows are fully independent, so any device count returns the
@@ -682,15 +684,25 @@ class BatchSimEngine:
     def _result(self, trace, admitted_hist, served_hist, *, completed,
                 dropped, residual, energy, swaps, elapsed, backend,
                 telem, dropped_slo=None, dropped_fault=None, retried=None,
-                qdrops=None) -> BatchSimResult:
+                qdrops=None, bins=None) -> BatchSimResult:
+        """``bins``: the pending answer of
+        :func:`~repro.sim.percentiles.certified_bins` where the histories
+        were on the device; the percentiles are then taken from it, and
+        only the designs it could not certify are recomputed here."""
         B, T, dt = self.platform.n_designs, trace.ticks, trace.dt
-        p50 = np.empty(B)
-        p99 = np.empty(B)
         with profiled("cosim_percentiles"):
-            for b in range(B):
-                p50[b], p99[b] = latency_percentiles(
-                    admitted_hist[:, b], served_hist[:, b], dt,
-                    queue_drops=None if qdrops is None else qdrops[:, b])
+            if bins is not None:
+                from repro.sim.percentiles import percentiles_from_bins
+                p50, p99 = percentiles_from_bins(
+                    bins, admitted_hist, served_hist, dt, qdrops)
+            else:
+                p50 = np.empty(B)
+                p99 = np.empty(B)
+                for b in range(B):
+                    p50[b], p99[b] = latency_percentiles(
+                        admitted_hist[:, b], served_hist[:, b], dt,
+                        queue_drops=None if qdrops is None
+                        else qdrops[:, b])
         sim_seconds = T * dt
         return BatchSimResult(
             n_designs=B, ticks=T, dt=dt,
@@ -1038,6 +1050,7 @@ class BatchSimEngine:
         from repro import shard as shard_mod
         from repro.core.perfmodel import (P_DYN_W, P_STATIC_W, V_BASE,
                                           V_SLOPE)
+        from repro.sim.percentiles import certified_bins
 
         p, cfg = self.platform, self.config
         B, A, T, dt = p.n_designs, p.n_tiles, trace.ticks, trace.dt
@@ -1429,10 +1442,12 @@ class BatchSimEngine:
                 *ys, obs_ys = ys
             if track:
                 admitted, served, qdropT = ys
-                qdrops = np.asarray(qdropT, dtype=np.float64)[:, :B]
             else:
-                admitted, served = ys
-                qdrops = None
+                (admitted, served), qdropT = ys, None
+            # the percentiles run on the device while the host fetches
+            bins = certified_bins(admitted, served, qdropT)
+            qdrops = (None if qdropT is None
+                      else np.asarray(qdropT, dtype=np.float64)[:, :B])
             (queueF, busyF, rttF, ratesF, guardF, polF, _ctlb, droppedF,
              energyF, swapsF, _fwdF, _capF, retryqF, dsloF, dfaultF,
              retriedF) = carryF
@@ -1547,7 +1562,7 @@ class BatchSimEngine:
             dropped_slo=dsloF.astype(np.float64),
             dropped_fault=dfaultF.astype(np.float64),
             retried=retriedF.astype(np.float64),
-            qdrops=qdrops)
+            qdrops=qdrops, bins=bins)
 
     # ------------------------------------------------------------ pallas
     def _pallas_args(self, trace):
@@ -1641,12 +1656,15 @@ class BatchSimEngine:
         against the NumPy float64 engine (f32 tolerance) and the scan
         backend."""
         from repro.kernels.tick_sim import fused_tick_sim
+        from repro.sim.percentiles import certified_bins
         p = self.platform
         B, A = p.n_designs, p.n_tiles
         args, plan, swaps_before = self._pallas_args(trace)
         prepare.close()
         with profiled("cosim_tick_loop") as loop:
             out = fused_tick_sim(**args)
+            # the percentiles run on the device while the host fetches
+            bins = certified_bins(out["adm"], out["served"])
             admitted = np.asarray(out["adm"], dtype=np.float64)
             served = np.asarray(out["served"], dtype=np.float64)
             queueF = np.asarray(out["queue"], dtype=np.float64)
@@ -1678,4 +1696,4 @@ class BatchSimEngine:
             energy=energyF, swaps=swapsF, elapsed=elapsed,
             backend="pallas", telem=None,
             dropped_slo=zB.copy(), dropped_fault=zB.copy(),
-            retried=zB.copy())
+            retried=zB.copy(), bins=bins)

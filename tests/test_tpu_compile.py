@@ -3,9 +3,9 @@ v5e chip that is described, not attached.
 
 The TPU compiler refuses what the CPU interpreter accepts (block shapes
 off the (8, 128) tiling, 3-D gathers, boolean selects), so these compiles
-guard the fused tick kernel and the flat-point sweep evaluator at their
-real sizes without a chip.  Nothing runs: a passing compile says nothing
-about results or times.
+guard the fused tick kernel, the flat-point sweep evaluator and the
+co-sim's device percentiles at their real sizes without a chip.  Nothing
+runs: a passing compile says nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -113,3 +113,14 @@ def test_flat_point_evaluator_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >= 3 * FLAT_POINTS * 4   # thr, e, mem
     assert mem.argument_size_in_bytes < 16 * 1024     # no per-point input
+
+
+def test_certified_percentiles_compile_for_v5e(one_chip):
+    """The co-sim's device percentiles at the faulted cell's size: 512
+    designs, 4,000 ticks, 2 tiles, queue drops tracked."""
+    from repro.sim.percentiles import _bins_jit
+    hist = jax.ShapeDtypeStruct((4000, B, 2), jnp.float32, sharding=one_chip)
+    compiled = _bins_jit.lower(hist, hist, hist).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes <= 16 * 1024     # (B, 3): bins, status
+    assert mem.temp_size_in_bytes < 2e9
