@@ -34,7 +34,7 @@ from repro.core.perfmodel import (NOC_POWER_SHARE, AccelWorkload,
 from repro.core.voltage import TECH_NODES, TECH_VARIANTS, TechModel
 
 
-def independent_islands_demo(n_tg: int, backend: str) -> None:
+def independent_islands_demo(n_tg: int) -> None:
     """Joint 3-accelerator sweep, shared vs per-island rate axes.
 
     The shared sweep only explores the diagonal of the rate space; the
@@ -48,8 +48,7 @@ def independent_islands_demo(n_tg: int, backend: str) -> None:
            for n in ("dfadd", "dfmul", "dfsin")]
     kw = dict(ks=(1, 2, 4), acc_rates=TILE_LADDER.levels(),
               noc_rates=(0.5, 1.0), tg_rates=(1.0,),
-              positions=((1, 1), (3, 3), (0, 2)), n_tg=n_tg,
-              backend=backend)
+              positions=((1, 1), (3, 3), (0, 2)), n_tg=n_tg)
     shared = grid_sweep(m, wls, **kw)
     indep = grid_sweep(m, wls, **kw, island_rates="independent",
                        chunk_points=200_000)
@@ -86,7 +85,7 @@ def independent_islands_demo(n_tg: int, backend: str) -> None:
           "sweep cannot see.")
 
 
-def tech_demo(node: int, variant: str, n_tg: int, backend: str) -> None:
+def tech_demo(node: int, variant: str, n_tg: int) -> None:
     """The V^2 f front diverges from the linear proxy's front.
 
     Sweeps the paper's 3-accelerator 4x4 SoC twice over the same
@@ -105,7 +104,7 @@ def tech_demo(node: int, variant: str, n_tg: int, backend: str) -> None:
     kw = dict(ks=(2, 4), acc_rates=(0.4, 0.7, 1.0, 1.3),
               noc_rates=(0.5, 1.0), tg_rates=(1.0,),
               positions=((1, 1), (3, 3), (0, 2)), n_tg=n_tg,
-              backend=backend, island_rates="independent")
+              island_rates="independent")
     lin = grid_sweep(m, wls, **kw)
     phys = grid_sweep(m, wls, **kw, tech_node=node, tech_variant=variant)
     # the trailing tech axis has size 1: flat indices line up
@@ -143,7 +142,6 @@ def main() -> None:
     ap.add_argument("--accel", default="dfadd", choices=sorted(CHSTONE))
     ap.add_argument("--tg", type=int, default=4,
                     help="active traffic generators")
-    ap.add_argument("--backend", default="numpy", choices=("numpy", "jax"))
     ap.add_argument("--independent-islands", action="store_true",
                     help="per-island rate axes (chunked sweep) + the "
                          "heterogeneous-dominance demo")
@@ -156,10 +154,10 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.tech_node is not None:
-        tech_demo(args.tech_node, args.tech_variant, args.tg, args.backend)
+        tech_demo(args.tech_node, args.tech_variant, args.tg)
         return
     if args.independent_islands:
-        independent_islands_demo(args.tg, args.backend)
+        independent_islands_demo(args.tg)
         return
 
     base, ai = CHSTONE[args.accel]
@@ -170,10 +168,10 @@ def main() -> None:
     res = grid_sweep(
         model, wl, ks=(1, 2, 4, 8),
         acc_rates=TILE_LADDER.levels(), noc_rates=NOC_LADDER.levels(),
-        tg_rates=TILE_LADDER.levels(), n_tg=args.tg, backend=args.backend)
+        tg_rates=TILE_LADDER.levels(), n_tg=args.tg)
     print(f"swept {len(res):,} design points for {args.accel} "
           f"(ai={ai}, {'compute' if wl.compute_bound else 'memory'}-bound) "
-          f"in {res.elapsed_s:.3f}s [{args.backend}]")
+          f"in {res.elapsed_s:.3f}s")
     print(summarize_result(res))
 
     # Spot-check the batched engine against the scalar reference path.

@@ -30,7 +30,7 @@ RPR006   backend-surface parity: the engines' keyword surfaces for
 CLI::
 
     python -m repro.analysis [--format text|json] [--baseline FILE]
-                             [--changed-only] [--bench] [paths...]
+                             [--changed-only] [paths...]
 
 Findings carry ``file:line``, rule id, rationale, and a stable
 fingerprint.  Pre-existing accepted findings live in the checked-in

@@ -6,7 +6,7 @@ findings exist (this is what the CI gate keys on); 2 on usage errors.
 Common invocations::
 
     python -m repro.analysis src/repro                 # full run
-    python -m repro.analysis --format json --bench     # CI gate
+    python -m repro.analysis --format json             # CI gate
     python -m repro.analysis --changed-only            # fast local loop
     python -m repro.analysis --write-baseline src/repro  # accept current
 """
@@ -20,7 +20,7 @@ from pathlib import Path
 from typing import List, Optional
 
 from repro.analysis.engine import analyze_paths
-from repro.analysis.findings import Finding, load_baseline, save_baseline
+from repro.analysis.findings import load_baseline, save_baseline
 
 DEFAULT_BASELINE = "analysis/baseline.json"
 DEFAULT_PATHS = ("src/repro",)
@@ -54,22 +54,16 @@ def _changed_files(root: Path) -> Optional[List[Path]]:
     return files
 
 
-def _print_text(report, *, bench_findings: List[Finding]) -> None:
-    def show(f: Finding, tag: str) -> None:
-        print(f"{f.location()}: {f.rule} [{tag}] {f.message}")
-
+def _print_text(report) -> None:
     for f in report.new:
-        show(f, "new")
-    for f in bench_findings:
-        show(f, "new")
+        print(f"{f.location()}: {f.rule} [new] {f.message}")
     if report.baselined:
         print(f"-- {len(report.baselined)} baselined finding(s) "
               "(see analysis/baseline.json)")
     if report.suppressed:
         print(f"-- {len(report.suppressed)} suppressed via "
               "# repro: noqa")
-    total_new = len(report.new) + len(bench_findings)
-    print(f"{report.modules} module(s) analyzed, {total_new} new, "
+    print(f"{report.modules} module(s) analyzed, {len(report.new)} new, "
           f"{len(report.baselined)} baselined, "
           f"{len(report.suppressed)} suppressed")
 
@@ -95,9 +89,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="analyze only files changed vs. git HEAD "
                          "(fast local loop; RPR006 parity checks run "
                          "only over the changed set)")
-    ap.add_argument("--bench", action="store_true",
-                    help="also run the BENCH001 trajectory gate over "
-                         "the repo's BENCH_*.json files")
     ap.add_argument("--out", default=None,
                     help="write the (JSON) report to this file as well")
     ap.add_argument("--root", default=None,
@@ -166,24 +157,16 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"wrote {len(keep)} finding(s) to {baseline_path}")
         return 0
 
-    bench_findings: List[Finding] = []
-    if args.bench:
-        from repro.analysis.bench import check_trajectories
-        bench_findings = check_trajectories(root)
-
     if args.format == "json" or args.out:
-        doc = report.to_dict()
-        doc["bench"] = [f.to_dict() for f in bench_findings]
-        doc["counts"]["new"] += len(bench_findings)
-        payload = json.dumps(doc, indent=2)
+        payload = json.dumps(report.to_dict(), indent=2)
         if args.format == "json":
             print(payload)
         if args.out:
             Path(args.out).write_text(payload + "\n")
     if args.format == "text":
-        _print_text(report, bench_findings=bench_findings)
+        _print_text(report)
 
-    return 1 if (report.new or bench_findings) else 0
+    return 1 if report.new else 0
 
 
 if __name__ == "__main__":

@@ -32,7 +32,7 @@ NOQA_RE = re.compile(
 
 @dataclass(frozen=True)
 class Finding:
-    rule: str                # "RPR001" ... "RPR006", "RPR000", "BENCH001"
+    rule: str                # "RPR001" ... "RPR006", "RPR000"
     path: str                # repo-relative, posix separators
     line: int                # 1-based
     message: str

@@ -98,7 +98,8 @@ PARITY: Tuple[Tuple[str, str, Dict[str, str]], ...] = (
         "tech": "accept",
         "tech_variant": "accept",
         "devices": "accept",
-        "backend": "accept",
+        # the sweep's path follows `devices=` alone
+        "backend": "absent",
     }),
 )
 

@@ -10,7 +10,7 @@ The NoC island DFS range is 10-100 MHz; the other islands 10-50 MHz, in
 5 MHz steps.  We keep those numbers verbatim: the perf model treats them as
 normalized rate ladders (f / f_max).
 
-This config drives the paper-claims benchmarks (Table I / Fig. 3 / Fig. 4).
+This config drives the paper-claims tests (Table I / Fig. 3 / Fig. 4).
 """
 from __future__ import annotations
 

@@ -6,9 +6,13 @@ Sweeps the paper's three design axes and reports Pareto-optimal points:
 * per-island rate assignment            (C2),
 * tile placement on the NoC grid        (Fig. 2's A1-near vs A2-far).
 
-Two evaluation backends: the analytic :class:`SoCPerfModel` (fast, used for
-sweeps and the paper-claims benchmarks) and the dry-run roofline
+Two performance models: the analytic :class:`SoCPerfModel` (fast, used for
+sweeps and the paper-claims tests) and the dry-run roofline
 (launch/dryrun.py), used to validate chosen points against compiled HLO.
+
+:func:`grid_sweep` has two evaluation paths, chosen by ``devices=`` alone:
+``None`` runs the float64 NumPy reference, an int or ``"auto"`` runs the
+flat-point jax evaluator on that many devices.
 
 Two evaluation *shapes*:
 
@@ -19,10 +23,10 @@ Two evaluation *shapes*:
   full cross-product (joint multi-accelerator K ladders x island-rate
   ladders x all grid placements) as broadcast axes, pushes the whole grid
   through ``SoCPerfModel.accel_throughput_batch`` in one vectorized call,
-  and returns a :class:`SweepResult` of flat objective arrays — millions
-  of design points per second, no per-point Python objects.  DesignPoints
-  are materialized lazily (:meth:`SweepResult.design_point`) only for the
-  handful of survivors (Pareto front / top-k).
+  and returns a :class:`SweepResult` of flat objective arrays, with no
+  per-point Python objects.  DesignPoints are materialized lazily
+  (:meth:`SweepResult.design_point`) only for the handful of survivors
+  (Pareto front / top-k).
 
 The Pareto front is sort-based O(N log N) (:func:`pareto_front_indices`);
 the O(N^2) brute force survives as :func:`pareto_front_bruteforce` for
@@ -331,7 +335,6 @@ class SweepResult(_SweepIndexing):
     valid: np.ndarray                   # (N,) bool (placement collisions out)
     mem_traffic: Optional[np.ndarray] = None   # (N,) float64, Fig.-4 model
     elapsed_s: float = 0.0
-    backend: str = "numpy"
 
     def __len__(self) -> int:
         return int(self.throughput.shape[0])
@@ -401,7 +404,6 @@ class ChunkedSweepResult(_SweepIndexing):
     n_chunks: int
     peak_chunk_bytes: int
     elapsed_s: float = 0.0
-    backend: str = "numpy"
 
     def __len__(self) -> int:
         return self.n_points
@@ -506,7 +508,7 @@ class _AxisLayout:
         return self.A + 2 + self.R + a
 
 
-def _eval_grid(model: SoCPerfModel, workloads, n_tg: int, backend: str,
+def _eval_grid(model: SoCPerfModel, workloads, n_tg: int,
                lay: _AxisLayout, vals: Dict[str, object], get,
                shape: Tuple[int, ...]) -> Dict[str, np.ndarray]:
     """Evaluate every objective over one (sub-)grid.
@@ -532,7 +534,7 @@ def _eval_grid(model: SoCPerfModel, workloads, n_tg: int, backend: str,
         thr = model.accel_throughput_batch(
             base_mbps=wl.base_mbps, wire_share=wl.wire_share, k=k_ax[a],
             f_acc=fa_ax[a], f_noc=fn_ax, f_tg=ft_ax, n_tg=n_tg,
-            pos_idx=pos_ax[a], backend=backend)
+            pos_idx=pos_ax[a])
         total_thr = total_thr + np.broadcast_to(thr, shape)
 
     area = np.zeros(shape, dtype=np.float64)
@@ -972,7 +974,6 @@ def grid_sweep(model: SoCPerfModel,
                tg_rates: Sequence[float] = (1.0,),
                positions: Optional[Sequence[Tuple[int, int]]] = None,
                n_tg: int = 0,
-               backend: str = "numpy",
                island_rates: str = "shared",
                chunk_points: Optional[int] = None,
                topk_track: int = 64,
@@ -1005,8 +1006,7 @@ def grid_sweep(model: SoCPerfModel,
     throughputs; area sums each accelerator's replication cost; energy is
     the mean accelerator-island chip power (each island at its own rate)
     plus the NoC share, per unit of total throughput; ``mem_traffic`` sums
-    each accelerator's offered MEM stream at its own island rate.  With
-    ``backend="jax"`` the throughput kernel runs jit-compiled.
+    each accelerator's offered MEM stream at its own island rate.
 
     **Chunked/streaming evaluation**: when ``chunk_points`` is given and
     the cross-product exceeds it, the grid is evaluated in fixed-size
@@ -1069,8 +1069,7 @@ def grid_sweep(model: SoCPerfModel,
             for o in ("throughput", "energy_per_unit", "mem_traffic"):
                 out[o] = out[o].astype(np.float64)
         else:
-            out = _eval_grid(model, workloads, n_tg, backend, lay, vals,
-                             get, shape)
+            out = _eval_grid(model, workloads, n_tg, lay, vals, get, shape)
         elapsed = time.perf_counter() - t0
         return SweepResult(
             axes=axes, shape=shape, workloads=workloads, n_tg=n_tg,
@@ -1079,7 +1078,7 @@ def grid_sweep(model: SoCPerfModel,
             energy_per_unit=out["energy_per_unit"].ravel(),
             valid=out["valid"].ravel(),
             mem_traffic=out["mem_traffic"].ravel(),
-            elapsed_s=elapsed, backend=backend)
+            elapsed_s=elapsed)
 
     # ---- chunked/streaming path: fixed-size blocks of whole trailing
     # panels; every block covers the contiguous global flat range
@@ -1123,8 +1122,8 @@ def grid_sweep(model: SoCPerfModel,
                                          shape, o0 * inner, o1 * inner,
                                          get, blk_shape, n_devices)
             else:
-                out = _eval_grid(model, workloads, n_tg, backend, lay,
-                                 vals, get, blk_shape)
+                out = _eval_grid(model, workloads, n_tg, lay, vals, get,
+                                 blk_shape)
                 flat = {k: v.ravel() for k, v in out.items()}
         n_chunks += 1
         peak_bytes = max(peak_bytes, sum(v.nbytes for v in flat.values())
@@ -1170,7 +1169,7 @@ def grid_sweep(model: SoCPerfModel,
         topk={o: topk[o]["i"] for o in objs},
         topk_track=topk_track, chunk_points=chunk_points,
         n_chunks=n_chunks, peak_chunk_bytes=int(peak_bytes),
-        elapsed_s=elapsed, backend=backend)
+        elapsed_s=elapsed)
 
 
 # ---------------------------------------------------------------------------

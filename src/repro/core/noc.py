@@ -2,7 +2,7 @@
 
 The paper's SoC is a 4x4 mesh NoC; a TPU pod is a 2D (v5e: 16x16) ICI
 torus.  Both are grids with per-link bandwidth and hop latency, so one model
-serves the paper-claims benchmarks (4x4, CHStone tiles) and the pod-scale
+serves the paper-claims tests (4x4, CHStone tiles) and the pod-scale
 perf model (16x16, layer tiles).
 
 Contention: per-link utilization rho from summed flows; the service

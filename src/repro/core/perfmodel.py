@@ -3,8 +3,8 @@
 Two roles:
 
 1. **Paper-claims engine** — evaluates the paper's 4x4 SoC (CHStone tiles,
-   five frequency islands) so benchmarks can reproduce Table I / Fig. 3 /
-   Fig. 4 shapes analytically.
+   five frequency islands) so the paper-claims tests reproduce Table I /
+   Fig. 3 / Fig. 4 shapes analytically.
 
 2. **Pod-scale engine** — turns dry-run artifacts (HLO FLOPs / bytes /
    collective bytes) + island rates into the three roofline terms used by
@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from functools import lru_cache
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -193,8 +192,8 @@ def _throughput_math(xp, base_mbps, wire_share, k, f_acc, f_noc, f_tg,
 
     ``xp`` is the array namespace (numpy or jax.numpy); every data argument
     broadcasts, so the same expression serves the scalar wrapper, the numpy
-    batch path, and the jitted jax path.  Kept in one place so the three
-    paths can never drift.
+    batch path, and the sweep's device evaluator (``core/dse.py``).  Kept
+    in one place so the three paths can never drift.
     """
     f_acc = xp.maximum(f_acc, 1e-3)
     f_noc = xp.maximum(f_noc, 1e-3)
@@ -208,32 +207,6 @@ def _throughput_math(xp, base_mbps, wire_share, k, f_acc, f_noc, f_tg,
     hopf0 = 1.0 + hop_latency_share * ref_hops
     t0 = (1.0 - w) + w * max(1.0, own_demand) * hopf0
     return base_mbps * t0 / t
-
-
-@lru_cache(maxsize=32)
-def _jitted_throughput_kernel(own_demand: float, tg_demand: float,
-                              link_bw: float, hop_latency_share: float,
-                              ref_hops: float):
-    """jax.jit-compiled throughput kernel, cached per model constants
-    (closed over as compile-time constants; built on first use; bounded —
-    many-model chunked sweeps must not pin one executable per config).
-
-    Note: runs at jax's default precision — enable jax_enable_x64 for
-    float64 parity with the numpy path; otherwise expect ~1e-6 relative
-    deviations from float32 rounding.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    def kernel(base_mbps, wire_share, k, f_acc, f_noc, f_tg, n_tg,
-               hop_counts):
-        return _throughput_math(
-            jnp, base_mbps, wire_share, k, f_acc, f_noc, f_tg, n_tg,
-            hop_counts, own_demand=own_demand, tg_demand=tg_demand,
-            link_bw=link_bw, hop_latency_share=hop_latency_share,
-            ref_hops=ref_hops)
-
-    return jax.jit(kernel)
 
 
 def _memory_traffic_math(xp, f_acc, f_noc, f_tg, n_tg, n_accels, *,
@@ -311,8 +284,7 @@ class SoCPerfModel:
     # -------------------------------------------------------- batched API
     def accel_throughput_batch(self, *, base_mbps, wire_share, k,
                                f_acc, f_noc, f_tg=1.0, n_tg=0,
-                               pos=None, pos_idx=None,
-                               backend: str = "numpy") -> np.ndarray:
+                               pos=None, pos_idx=None) -> np.ndarray:
         """Throughput (MB/s) for a stacked batch of design points.
 
         Every argument broadcasts against the others (numpy rules), so a
@@ -326,22 +298,15 @@ class SoCPerfModel:
         * ``n_tg`` — active traffic generators (scalar or array),
         * ``pos`` (one (r, c) or (..., 2) array) or ``pos_idx`` (flat node
           indices) — tile placements, resolved through the precomputed
-          hop matrix (no per-point route walks),
-        * ``backend`` — ``"numpy"`` (float64, the parity reference) or
-          ``"jax"`` (jit-compiled; float32 unless jax_enable_x64).
+          hop matrix (no per-point route walks).
+
+        The math runs in float64 NumPy, the sweep's reference.
         """
         hop_counts = self.hop_counts(pos=pos, pos_idx=pos_idx)
         consts = dict(own_demand=self.own_demand, tg_demand=self.tg_demand,
                       link_bw=self.noc.link_bw,
                       hop_latency_share=self.hop_latency_share,
                       ref_hops=self._ref_hops())
-        if backend == "jax":
-            kern = _jitted_throughput_kernel(
-                self.own_demand, self.tg_demand, self.noc.link_bw,
-                self.hop_latency_share, float(consts["ref_hops"]))
-            out = kern(base_mbps, wire_share, k, f_acc, f_noc, f_tg, n_tg,
-                       hop_counts)
-            return np.asarray(out)
         arrs = [np.asarray(a, dtype=np.float64)
                 for a in (base_mbps, wire_share, k, f_acc, f_noc, f_tg, n_tg)]
         return _throughput_math(np, *arrs, hop_counts, **consts)

@@ -18,7 +18,7 @@ into ``(replica=K, shard=model/K)``:
   bridge = one all-to-all resharding collective at the tile boundary),
 * each replica's collectives span model/K chips — (K-1)/K fewer bytes on
   the wire and 1/K the hop latency: the throughput gain for
-  communication-bound tiles (measured in benchmarks/bench_replication.py).
+  communication-bound tiles.
 
 Because each design point is a separate compiled program (the paper builds
 a separate bitstream per K), a K-factored run uses ``make_mra_mesh`` — the

@@ -7,8 +7,8 @@ A small, dependency-free metrics facility in the Prometheus data model:
 - :func:`MetricsRegistry.render_prometheus` emits the Prometheus text
   exposition format (``# HELP`` / ``# TYPE`` / ``name{label="x"} value``).
 - :func:`parse_prometheus_text` parses that format back into plain dicts —
-  used by the round-trip tests and the CI bench gate, and handy for
-  scraping ``BENCH_*`` artifacts without a Prometheus server.
+  used by the round-trip tests, and handy for scraping an exposition
+  without a Prometheus server.
 - :func:`telemetry_timeseries` converts a :class:`repro.sim.telemetry.Telemetry`
   (or ``BatchTelemetry`` design view) ring into a JSON-safe timeseries doc.
 

@@ -23,7 +23,8 @@ from repro.core.islands import (IslandConfig, IslandSpec, NOC_LADDER,
 from repro.core.noc import (Flow, NocConfig, NocModel, hops, hops_batch,
                             link_loads_batch, positions_to_indices,
                             route_max_utilization, routing_tables, xy_route)
-from repro.core.perfmodel import AccelWorkload, SoCPerfModel, chip_power
+from repro.core.perfmodel import (AccelWorkload, SoCPerfModel, _throughput_math,
+                                  chip_power)
 
 NOCS = [NocConfig(4, 4), NocConfig(4, 4, torus=True),
         NocConfig(3, 5), NocConfig(5, 3, torus=True)]
@@ -120,15 +121,24 @@ def test_throughput_batch_matches_scalar_random(torus):
 
 
 def test_throughput_jax_backend_close_to_numpy():
+    """The throughput math the device evaluator runs, jitted over
+    jax.numpy, against the float64 NumPy batch path."""
+    import jax
+    import jax.numpy as jnp
+
     m = SoCPerfModel()
     ks = np.array([1.0, 2.0, 4.0])[:, None]
     fa = np.array([0.2, 0.6, 1.0])[None, :]
     a = m.accel_throughput_batch(base_mbps=4.61, wire_share=0.035, k=ks,
                                  f_acc=fa, f_noc=0.5, f_tg=1.0, n_tg=4,
                                  pos=(3, 3))
-    b = m.accel_throughput_batch(base_mbps=4.61, wire_share=0.035, k=ks,
-                                 f_acc=fa, f_noc=0.5, f_tg=1.0, n_tg=4,
-                                 pos=(3, 3), backend="jax")
+    consts = dict(own_demand=m.own_demand, tg_demand=m.tg_demand,
+                  link_bw=m.noc.link_bw,
+                  hop_latency_share=m.hop_latency_share,
+                  ref_hops=float(m._ref_hops()))
+    kern = jax.jit(lambda *arrs: _throughput_math(jnp, *arrs, **consts))
+    b = np.asarray(kern(4.61, 0.035, ks, fa, 0.5, 1.0, 4,
+                        m.hop_counts(pos=(3, 3))))
     # jax default precision is float32 unless jax_enable_x64
     np.testing.assert_allclose(b, a, rtol=1e-5)
 

@@ -86,7 +86,7 @@ def _host_decoded_sweep(model, kw):
         dse._objectives, *dse._model_scalars(model, WLS, kw["n_tg"])))
     thr, energy, mem = (np.asarray(o, dtype=np.float64) for o in
                         math(*_host_inputs(model, lay, vals, shape, 0, n)))
-    host = dse._eval_grid(model, WLS, kw["n_tg"], "numpy", lay, vals,
+    host = dse._eval_grid(model, WLS, kw["n_tg"], lay, vals,
                           lambda dim, v: dse._axis(v, dim, len(shape)),
                           shape)
     return dse.SweepResult(

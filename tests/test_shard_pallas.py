@@ -228,7 +228,6 @@ def test_jit_cache_bounded_eviction():
 def test_module_caches_bounded_over_1k_configs():
     from repro.core import dse as dse_mod
     from repro.core import noc as noc_mod
-    from repro.core import perfmodel as pm
 
     # noc: a 1k-distinct-config stream through the table/route caches
     for i in range(1000):
@@ -243,12 +242,6 @@ def test_module_caches_bounded_over_1k_configs():
             (fn.__name__, info)
     assert noc_mod.routing_tables.cache_info().currsize \
         <= noc_mod._TABLE_CACHE_SIZE
-
-    # perfmodel: 1k distinct model-constant tuples -> bounded jit cache
-    for i in range(1000):
-        pm._jitted_throughput_kernel(0.1 + i * 1e-4, 0.07, 1.0, 0.03, 2.0)
-    info = pm._jitted_throughput_kernel.cache_info()
-    assert info.currsize <= 32, info
 
     # dse: the sharded flat-point evaluator cache is scalar-keyed + bounded
     # (the layout is two flags; point counts and tables are call data)
